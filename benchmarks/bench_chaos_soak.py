@@ -42,6 +42,7 @@ import time
 from repro import faults
 from repro.api import gpu_request, price
 from repro.core.engine import Explorer
+from repro.core.engine.pool import host_env
 from repro.core.specs import star_stencil_3d
 from repro.serve import PriceClient, PricingDaemon
 from repro.serve.daemon import can_bind_unix_sockets
@@ -335,7 +336,7 @@ def main():
         # jax forces the forkserver pool start method, whose workers cannot
         # inherit this process's in-memory fault plan — re-exec the bench in
         # a clean interpreter where plain fork is available
-        env = dict(os.environ)
+        env = host_env()
         env.pop(faults.ENV_VAR, None)
         env.pop("REPRO_POOL_DEADLINE_S", None)
         proc = subprocess.run(

@@ -45,6 +45,7 @@ import time
 from repro import durable, faults
 from repro.api import gpu_request, price
 from repro.core.engine import Explorer
+from repro.core.engine.pool import host_env
 from repro.core.specs import star_stencil_3d
 from repro.serve import PriceClient
 from repro.serve.daemon import can_bind_unix_sockets
@@ -69,7 +70,7 @@ def ranking_key(result):
 
 def _src_env():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = host_env()
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
@@ -316,7 +317,7 @@ def _main_impl():
 
 def main():
     if "jax" in sys.modules:
-        env = dict(os.environ)
+        env = host_env()
         env.pop(faults.ENV_VAR, None)
         proc = subprocess.run(
             [sys.executable, "-m", "benchmarks.bench_crash_resume"], env=env)

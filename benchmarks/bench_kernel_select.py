@@ -2,7 +2,7 @@
 kernels (the autotuning replacement) through the exploration engine — one
 Explorer (and one invariant cache) prices every generator's decision space —
 plus a correctness spot-check of the selected kernel against the jnp oracle
-in interpret mode."""
+(interpreted on the CPU, compiled by Mosaic on a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -59,7 +59,7 @@ def main():
         f"{sum(r.cache_stats['misses'] for r in reports)} misses",
     )
 
-    # correctness of a selected stencil config (small domain, interpret mode)
+    # correctness of a selected stencil config (small domain)
     from repro.kernels.stencil3d25.ops import star_stencil
     from repro.kernels.stencil3d25.ref import pad_input, star_stencil_ref, star_weights
 

@@ -8,6 +8,12 @@ Set ``REPRO_TRACE_DIR=<dir>`` to capture one Perfetto-loadable Chrome
 trace per module (``<dir>/<module>.trace.json``, DESIGN.md §14): telemetry
 is enabled for the whole run and the span buffer is dumped and reset
 between modules, so each trace shows exactly that benchmark's pipeline.
+
+JAX's persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR``
+says, else to ``<checkout>/.jax-cache``; it is set through the environment,
+so this process does not import JAX before a module does.  Modules that start pricing or
+tracing children pin them to the CPU (``JAX_PLATFORMS=cpu``), so only this
+process ever holds an accelerator.
 """
 from __future__ import annotations
 
@@ -52,6 +58,9 @@ def _dump_trace(trace_dir: str | None, name: str) -> None:
 def main() -> None:
     import importlib
 
+    from repro.kernels import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     trace_dir = os.environ.get("REPRO_TRACE_DIR")
     if trace_dir:
         from repro import obs

@@ -7,8 +7,8 @@
 3. Inspect the predicted volumes/limiters; cross-check one config against the
    exact LRU cache-simulator oracle.
 4. Do the same on the TPU side: select a Pallas block configuration
-   analytically and run the selected kernel (interpret mode) against the
-   jnp oracle.
+   analytically and run the selected kernel (interpreted on the CPU,
+   compiled by Mosaic on a TPU) against the jnp oracle.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
@@ -49,7 +49,12 @@ print(f"\nvalidation vs LRU simulator ({best.launch.block}): "
       f"simulated {sim['dram_load_bytes_per_lup']:.1f} B/LUP")
 
 # ---------------------------------------------------------------- TPU side
-import jax
+import os
+
+from repro.kernels import use_compile_cache
+
+use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import jax  # after the compile-cache environment is set
 
 from repro.kernels.stencil3d25.generator import rank_configs as tpu_rank
 from repro.kernels.stencil3d25.ops import star_stencil

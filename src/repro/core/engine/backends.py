@@ -62,6 +62,7 @@ from ..perfmodel import (
     gpu_rate_matrix,
     l1_rates,
 )
+from ..tpu_adapt import vmem_violation
 from .protocol import EvalResult, RejectedSpec, SkipConfig, Task
 
 # Relative slack applied to the GPU closed-form bounds: the model computes
@@ -355,10 +356,8 @@ class PallasBackend:
             raise SkipConfig(spec.reason)
         est = values[("pallas", spec, machine)]
         if not est.feasible:
-            raise SkipConfig(
-                f"VMEM layer condition violated: {est.vmem_alloc_bytes} B "
-                f"allocated > {machine.vmem_bytes} B VMEM"
-            )
+            raise SkipConfig(vmem_violation(
+                est.vmem_alloc_bytes, est.detail["vmem_reserve"], machine))
         return config, est, est.work_rate, est.limiter
 
     def sort_key(self, result: EvalResult) -> tuple:
@@ -411,10 +410,9 @@ class PallasBackend:
             orders.append(pos_arr[order[feasible[order, m]]])
             mskips = list(rejected)
             for i in np.flatnonzero(~feasible[:, m]):
-                alloc = structs[i]["vmem_alloc"]
-                mskips.append((live_pos[i], (
-                    f"SkipConfig: VMEM layer condition violated: "
-                    f"{alloc} B allocated > {machine.vmem_bytes} B VMEM")))
+                reason = vmem_violation(structs[i]["vmem_alloc"],
+                                        structs[i]["vmem_reserve"], machine)
+                mskips.append((live_pos[i], f"SkipConfig: {reason}"))
             skips.append(mskips)
         return orders, skips
 
@@ -430,8 +428,6 @@ class PallasBackend:
             raise SkipConfig(spec.reason)
         est = estimate_pallas(spec, machine)
         if not est.feasible:
-            raise SkipConfig(
-                f"VMEM layer condition violated: {est.vmem_alloc_bytes} B "
-                f"allocated > {machine.vmem_bytes} B VMEM"
-            )
+            raise SkipConfig(vmem_violation(
+                est.vmem_alloc_bytes, est.detail["vmem_reserve"], machine))
         return config, est, est.work_rate, est.limiter

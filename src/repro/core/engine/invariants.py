@@ -70,7 +70,9 @@ from repro import durable, faults, obs
 #   3 — geometry-factored keys: wave keys/args carry GPUGeometry objects
 #       (not ad-hoc tuples / whole machines) and the machine-axis path adds
 #       the geometry-keyed pallas-struct task (DESIGN.md §11)
-ENGINE_CACHE_VERSION = 3
+#   4 — Pallas feasibility counts Mosaic's VMEM reserve beside the
+#       footprint (pallas estimates and pallas-struct entries carry it)
+ENGINE_CACHE_VERSION = 4
 
 _MAGIC = b"repro-invariant-cache"
 

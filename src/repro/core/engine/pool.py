@@ -136,6 +136,25 @@ def default_workers() -> int:
     return max(n, 1)
 
 
+def host_env(env=None) -> dict:
+    """A copy of ``env`` (default: this process's) for a child process
+    that prices or traces: JAX pinned to the CPU, so only the process that
+    runs kernels ever holds the accelerator."""
+    out = dict(os.environ if env is None else env)
+    out["JAX_PLATFORMS"] = "cpu"
+    return out
+
+
+def _pin_worker_to_cpu() -> None:
+    """Pool initializer.  Tasks are pure host arithmetic and never touch
+    JAX, but a forkserver/spawn worker re-imports ``__main__``, which may
+    import JAX; pinning it to the CPU before any task runs means no worker
+    can ever initialize the accelerator backend."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def _context():
     """Pick a start method: plain fork is fastest, but forking a process
     whose XLA/JAX runtime already spawned threads can deadlock — fall back
@@ -243,7 +262,8 @@ class TaskPool:
                 return None
             try:
                 self._executor = ProcessPoolExecutor(
-                    max_workers=self.workers, mp_context=ctx)
+                    max_workers=self.workers, mp_context=ctx,
+                    initializer=_pin_worker_to_cpu)
             except (OSError, ValueError, RuntimeError):
                 self._broken = True
         return self._executor

@@ -152,7 +152,12 @@ class TPUMachine:
     peak_flops_f32: float = 197e12 / 4
     hbm_bw: float = 819e9              # B/s per chip
     hbm_bytes: int = 16 * 1024**3
-    vmem_bytes: int = 128 * 1024 * 1024  # model constant (per-core VMEM budget)
+    # per-core VMEM budget: the largest footprint Mosaic compiles on v5e.
+    # Compile probes against a described v5e (JAX 0.9.0, libtpu 0.0.34):
+    # 4 x 32 MiB double-buffered blocks compile at vmem_limit_bytes=128 MiB
+    # and not at 127 MiB; 132 MiB fails at any limit ("Used 132.00M of
+    # 128.00M vmem").  Larger limits are accepted but buy nothing.
+    vmem_bytes: int = 128 * 1024 * 1024
     vmem_bw: float = 4.0e12            # B/s VMEM<->VREG model constant
     ici_bw_per_link: float = 50e9      # B/s per link per direction
     ici_links: int = 4                 # 2D torus: 4 links/chip (2 axes x 2 dirs)
@@ -183,6 +188,20 @@ class TPUMachine:
 
 
 TPU_V5E = TPUMachine()
+
+# JAX ``device_kind`` -> the machine model that prices it.  A kind missing
+# here has no model: it is an error, never priced as a v5e by default.
+DEVICE_KINDS: dict = {"TPU v5 lite": TPU_V5E}
+
+
+def machine_for_device(kind: str) -> TPUMachine:
+    """The TPU model for a JAX ``device_kind`` (KeyError when unknown)."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(
+            f"no machine model for device kind {kind!r}; "
+            f"known: {sorted(DEVICE_KINDS)}") from None
 
 
 # --------------------------------------------------------------------------

@@ -39,6 +39,21 @@ def _roundup(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+# VMEM Mosaic keeps beside a kernel's blocks and scratch (``vmem_reserve``):
+# working copies of what the body reads (relayouts of unaligned windows,
+# concatenations) -- at most one copy of the input blocks and scratch -- or
+# the f32 results of its dots, whichever is larger, plus this much of its
+# own.  Calibrated against compiles for a described v5e at the six
+# generators' real sizes (JAX 0.9.0, libtpu 0.0.34), bisecting the least
+# limit that compiles: stencil ring needs 14.1 MiB beyond its footprint
+# (bound 17.1), stencil replane 11.7 (17.7), flash attention (1024, 2048)
+# 8.9 (12.5), ytile_ring ty=256 8.1 (16.9), matmul 1024x2048x1024 4.5
+# (16.0), LBM replane 2.5 (11.0); jacobi ty=1024 compiles at no limit and
+# is skipped.  ``python -m repro.kernels.compile_probe`` checks every
+# candidate against the compiler.
+VMEM_BASE_RESERVE_BYTES = 4 * 1024 * 1024
+
+
 @memoize_hash
 @dataclass(frozen=True)
 class OperandSpec:
@@ -47,6 +62,9 @@ class OperandSpec:
     ``grid_deps``: grid dims (indices into the kernel grid) the index map
     depends on.  ``revisit=False`` forces per-step refetch (e.g. dynamic,
     data-dependent index maps where Mosaic cannot prove equality).
+    ``fetches``: exact fetch count where the closed form over ``grid_deps``
+    over-counts (two grid dims stepping one block coordinate, see
+    ``linear_fetch_count``); ``None`` means the closed form is exact.
     """
 
     name: str
@@ -56,6 +74,7 @@ class OperandSpec:
     is_output: bool = False
     n_buffers: int = 2          # double-buffered pipeline default
     revisit: bool = True
+    fetches: int | None = None
 
     def block_bytes(self) -> int:
         return math.prod(self.block_shape) * self.elem_bytes
@@ -122,6 +141,26 @@ def fetch_count(grid: tuple, grid_deps: tuple, revisit: bool = True) -> int:
     return out
 
 
+def linear_fetch_count(grid: tuple, coeffs) -> int:
+    """Exact fetches for a purely linear index map, ``coeffs[j][d]`` being
+    grid dim d's coefficient in block coordinate j.
+
+    The step that increments dim d resets every dim e > d, so it moves
+    coordinate j by ``c[j][d] - sum_{e>d} c[j][e] * (grid[e] - 1)``
+    wherever it happens, and it happens ``prod(grid[:d]) * (grid[d] - 1)``
+    times.  Steps that move no coordinate are elided.  Equals
+    ``fetch_count`` unless two grid dims step one coordinate.
+    """
+    n = 1
+    for d, g in enumerate(grid):
+        moves = any(
+            row[d] - sum(row[e] * (grid[e] - 1) for e in range(d + 1, len(grid)))
+            for row in coeffs)
+        if g > 1 and moves:
+            n += math.prod(grid[:d]) * (g - 1)
+    return n
+
+
 def fetch_count_oracle(grid: tuple, index_map: Callable, revisit: bool = True) -> int:
     """Explicit grid walk (the listing-5 analogue for TPU) — test oracle."""
     from itertools import product
@@ -175,7 +214,8 @@ def hbm_traffic(spec: PallasKernelSpec) -> tuple:
     hbm_bytes = 0.0
     per_op = {}
     for op in spec.operands:
-        fetches = fetch_count(spec.grid, op.grid_deps, op.revisit)
+        fetches = op.fetches if op.fetches is not None else \
+            fetch_count(spec.grid, op.grid_deps, op.revisit)
         # short-row DMA efficiency: rows shorter than the 256B granule waste bw
         row_bytes = op.block_shape[-1] * op.elem_bytes if op.block_shape else op.elem_bytes
         eff = min(1.0, row_bytes / 256.0) if row_bytes < 256 else 1.0
@@ -183,6 +223,52 @@ def hbm_traffic(spec: PallasKernelSpec) -> tuple:
         per_op[op.name] = {"fetches": fetches, "bytes": vol, "dma_eff": eff}
         hbm_bytes += vol / max(eff, 1e-6)
     return hbm_bytes, per_op
+
+
+def vmem_footprint(operands, scratch_bytes: int, geometry) -> int:
+    """VMEM a pallas_call allocates, as the feasibility check counts it:
+    scratch plus every pipelined block padded to the (sublane, lane) tile,
+    times its buffer count."""
+    alloc = scratch_bytes
+    for op in operands:
+        alloc += op.vmem_block_bytes(geometry) * op.n_buffers
+    return alloc
+
+
+def vmem_reserve(operands, scratch_bytes: int, dots, geometry) -> int:
+    """VMEM Mosaic needs beyond ``vmem_footprint``: the larger of one copy
+    of the input blocks and scratch, and the f32 results of the step's
+    dots (``(m, n)`` shapes), plus ``VMEM_BASE_RESERVE_BYTES``."""
+    reads = scratch_bytes + sum(op.vmem_block_bytes(geometry)
+                                for op in operands if not op.is_output)
+    results = sum(OperandSpec("", tuple(d), 4).vmem_block_bytes(geometry)
+                  for d in dots)
+    return VMEM_BASE_RESERVE_BYTES + max(reads, results)
+
+
+def vmem_limit_bytes(operands, scratch_bytes: int, dots=(),
+                     machine: TPUMachine = TPU_V5E) -> int:
+    """Mosaic's scoped-VMEM limit for a kernel: its footprint plus its
+    reserve, capped at the machine's VMEM.  Exactly the room the
+    feasibility check requires to fit, so a candidate it passes gets the
+    room it was priced with."""
+    need = vmem_footprint(operands, scratch_bytes, machine) \
+        + vmem_reserve(operands, scratch_bytes, dots, machine)
+    return min(machine.vmem_bytes, need)
+
+
+def spec_vmem_reserve(spec: PallasKernelSpec, geometry) -> int:
+    """``vmem_reserve`` of a spec: its dots are its per-step matmuls."""
+    return vmem_reserve(spec.operands, spec.scratch_bytes,
+                        [(m.m, m.n) for m in spec.matmuls_per_step], geometry)
+
+
+def vmem_violation(vmem_alloc, vmem_reserve_bytes, machine) -> str:
+    """Skip reason for a candidate whose footprint plus reserve exceeds the
+    machine's VMEM."""
+    return (f"VMEM layer condition violated: {vmem_alloc} B allocated + "
+            f"{vmem_reserve_bytes} B compiler reserve > "
+            f"{machine.vmem_bytes} B VMEM")
 
 
 def pallas_time_floor(spec: PallasKernelSpec,
@@ -211,9 +297,8 @@ def pallas_structure(spec: PallasKernelSpec, geometry) -> dict:
     """
     n_steps = math.prod(spec.grid) if spec.grid else 1
     hbm_bytes, per_op = hbm_traffic(spec)
-    vmem_alloc = spec.scratch_bytes
-    for op in spec.operands:
-        vmem_alloc += op.vmem_block_bytes(geometry) * op.n_buffers
+    vmem_alloc = vmem_footprint(spec.operands, spec.scratch_bytes, geometry)
+    vmem_reserve_bytes = spec_vmem_reserve(spec, geometry)
     mxu_flops = sum(m.padded_flops(geometry, spec.elem_bytes)
                     for m in spec.matmuls_per_step)
     vpu_elems = spec.vpu_elems_per_step
@@ -230,6 +315,7 @@ def pallas_structure(spec: PallasKernelSpec, geometry) -> dict:
         "hbm_bytes": hbm_bytes,
         "per_op": per_op,
         "vmem_alloc": vmem_alloc,
+        "vmem_reserve": vmem_reserve_bytes,
         "mxu_flops": mxu_flops,
         "vpu_elems": vpu_elems,
         "vmem_touch": vmem_touch,
@@ -260,7 +346,7 @@ def pallas_rate_matrix(structs, machines):
     mxu_flops = f(s["mxu_flops"] for s in structs)
     vpu_elems = f(s["vpu_elems"] for s in structs)
     vmem_touch = f(s["vmem_touch"] for s in structs)
-    vmem_alloc = f(s["vmem_alloc"] for s in structs)
+    vmem_need = f(s["vmem_alloc"] + s["vmem_reserve"] for s in structs)
     bf16 = np.array([s["elem_bytes"] <= 2 for s in structs], dtype=bool)
 
     hbm_bw = f(m.hbm_bw for m in machines)
@@ -288,7 +374,7 @@ def pallas_rate_matrix(structs, machines):
     limiter_idx = np.where(
         last_max == 0, np.where(mxu_time >= vpu_time, 0, 1),
         np.where(last_max == 1, 2, 3))
-    feasible = vmem_alloc[:, None] <= vmem_bytes[None, :]
+    feasible = vmem_need[:, None] <= vmem_bytes[None, :]
     return total, limiter_idx, feasible
 
 
@@ -300,10 +386,9 @@ def estimate_pallas(spec: PallasKernelSpec, machine: TPUMachine = TPU_V5E) -> Pa
     hbm_time = hbm_bytes / machine.hbm_bw
 
     # ---- VMEM residency (layer condition as feasibility) ---------------
-    vmem_alloc = spec.scratch_bytes
-    for op in spec.operands:
-        vmem_alloc += op.vmem_block_bytes(machine) * op.n_buffers
-    feasible = vmem_alloc <= machine.vmem_bytes
+    vmem_alloc = vmem_footprint(spec.operands, spec.scratch_bytes, machine)
+    vmem_reserve_bytes = spec_vmem_reserve(spec, machine)
+    feasible = vmem_alloc + vmem_reserve_bytes <= machine.vmem_bytes
 
     # ---- compute issue model -------------------------------------------
     mxu_flops = sum(m.padded_flops(machine, spec.elem_bytes) for m in spec.matmuls_per_step)
@@ -343,7 +428,8 @@ def estimate_pallas(spec: PallasKernelSpec, machine: TPUMachine = TPU_V5E) -> Pa
         limiter=limiter,
         feasible=feasible,
         work=spec.work_per_step * n_steps,
-        detail={"per_operand": per_op, "n_steps": n_steps},
+        detail={"per_operand": per_op, "n_steps": n_steps,
+                "vmem_reserve": vmem_reserve_bytes},
     )
 
 

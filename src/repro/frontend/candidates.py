@@ -8,11 +8,11 @@ callback returning a :class:`KernelBuild` (the kernel's calling convention,
 placeholder args, and cost annotations), and the frontend traces each
 configuration into its spec mechanically.
 
-Configurations the tracer rejects yield ``(config, RejectedSpec(reason))``
-pairs: the exploration engine's Pallas backend resolves those to
-``report.skipped`` entries carrying the tracing diagnostic, so a non-affine
-kernel shows up as an actionable skip reason in the ranking report instead
-of an exception mid-sweep.
+Configurations the tracer rejects, and those whose blocks Mosaic cannot
+tile, yield ``(config, RejectedSpec(reason))`` pairs: the exploration
+engine's Pallas backend resolves those to ``report.skipped`` entries
+carrying the diagnostic, so a non-affine kernel shows up as an actionable
+skip reason in the ranking report instead of an exception mid-sweep.
 """
 from __future__ import annotations
 
@@ -20,6 +20,7 @@ from dataclasses import dataclass
 from typing import Callable, Iterable, Iterator
 
 from repro.core.engine.protocol import RejectedSpec
+from repro.core.machines import TPU_V5E
 
 from .lower import CostModel, lower_tpu
 from .trace import TraceError, trace_kernel
@@ -67,7 +68,29 @@ def candidates(build: Callable, space: Iterable,
         except TraceError as e:
             yield config, RejectedSpec(kb.name, str(e))
             continue
+        untileable = _untileable_block(traced)
+        if untileable:
+            yield config, RejectedSpec(kb.name, untileable)
+            continue
         yield config, spec
+
+
+def _untileable_block(traced, machine=TPU_V5E) -> str | None:
+    """Mosaic's block rule: each of the last two block dims divides by its
+    tile (sublanes, lanes) or equals the array's own dim.  The tile is the
+    same for every dtype: compile probes on v5e accept bf16 blocks of 8
+    rows.  A candidate that breaks it cannot compile, so it is no
+    candidate."""
+    tile = (machine.vpu_sublanes, machine.vpu_lanes)
+    for op in traced.operands:
+        pairs = zip(op.block_shape[-2:], op.arg_shape[-2:],
+                    tile[-len(op.block_shape):])
+        if any(b % t and b != n for b, n, t in pairs):
+            return (f"operand {op.name!r}: block {op.block_shape} of array "
+                    f"{op.arg_shape} does not tile on TPU (the last two "
+                    f"block dims must divide by {tile} or equal the "
+                    f"array's)")
+    return None
 
 
 def grid_space(**axes) -> Iterator[dict]:

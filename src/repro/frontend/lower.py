@@ -29,10 +29,16 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from repro.core.access import Access, Field, KernelSpec
-from repro.core.tpu_adapt import MatmulShape, OperandSpec, PallasKernelSpec
+from repro.core.tpu_adapt import (
+    MatmulShape,
+    OperandSpec,
+    PallasKernelSpec,
+    fetch_count,
+    linear_fetch_count,
+)
 
-from .affine import AffineExpr, affine
-from .trace import BodyAccess, TraceError, TracedKernel
+from .affine import AffineExpr, NonAffineError, affine
+from .trace import BodyAccess, TraceError, TracedKernel, grid_sym
 
 
 @dataclass(frozen=True)
@@ -88,6 +94,21 @@ def derive_costs(traced: TracedKernel, base: CostModel | None = None) -> CostMod
 # --------------------------------------------------------------------------
 # TPU lowering
 # --------------------------------------------------------------------------
+def _exact_fetches(grid: tuple, op) -> int | None:
+    """The operand's fetch count where the closed form over its grid deps
+    over-counts (linear index maps with two grid dims stepping one block
+    coordinate); ``None`` where the closed form is exact or the map is
+    quasi-affine."""
+    try:
+        rows = [e.as_linear()[0] for e in op.index_exprs]
+    except NonAffineError:
+        return None
+    coeffs = [[row.get(grid_sym(d), 0) for d in range(len(grid))]
+              for row in rows]
+    n = linear_fetch_count(grid, coeffs)
+    return None if n == fetch_count(grid, op.grid_deps) else n
+
+
 def lower_tpu(traced: TracedKernel, costs: CostModel | None = None,
               name: str | None = None) -> PallasKernelSpec:
     """BlockSpecs are the address expressions: emit the Pallas estimator
@@ -100,6 +121,7 @@ def lower_tpu(traced: TracedKernel, costs: CostModel | None = None,
             elem_bytes=op.elem_bytes,
             grid_deps=op.grid_deps,
             is_output=op.is_output,
+            fetches=_exact_fetches(traced.grid, op),
         )
         for op in traced.operands
     )

@@ -10,10 +10,10 @@ artifact the estimator requires from a code generator (paper §1.2):
   * per operand, the **address expression**: the BlockSpec index map
     evaluated over symbolic grid coordinates (``affine.Sym``), from which
     grid dependence, revisit behaviour, and HBM volumes follow exactly;
-  * optionally (``trace_body=True``) the kernel body's ``pl.load`` /
-    ``pl.store`` / ref-indexing accesses over symbolic coordinates, plus
-    elementwise-op and matmul counts — enough to lower thread-level affine
-    maps for the GPU estimator and to derive default cost models.
+  * optionally (``trace_body=True``) the kernel body's ref-indexing
+    accesses over symbolic coordinates, plus elementwise-op and matmul
+    counts — enough to lower thread-level affine maps for the GPU
+    estimator and to derive default cost models.
 
 Kernels outside the affine contract are rejected with a precise diagnostic
 naming the offending access (``TraceError``), which the exploration engine
@@ -275,6 +275,34 @@ class SymArray:
     max = sum
     min = sum
     mean = sum
+
+    def __getitem__(self, idx):
+        """Static unit-stride slice of a loaded value: a narrower window of
+        the same ref, or of a derived array."""
+        ctx = self.ctx
+        if not isinstance(idx, tuple):
+            idx = (idx,)
+        idx = idx + (slice(None),) * (self.ndim - len(idx))
+        if len(idx) != self.ndim or any(
+                not isinstance(i, slice) or i.step not in (None, 1)
+                for i in idx):
+            raise TraceError(ctx.name, "value slice",
+                             f"only static unit-stride slices of a loaded "
+                             f"value are traceable, got {idx!r}")
+        bounds = [i.indices(size)[:2] for i, size in zip(idx, self.shape)]
+        shape = tuple(stop - start for start, stop in bounds)
+        if self.view is None:
+            ctx.body.notes.append(
+                "slice of a derived (non-ref) array: per-point address "
+                "expressions unavailable for it")
+            return SymArray(shape, self.dtype, None, ctx)
+        v = self.view
+        offsets, extents = list(v.offsets), list(v.extents)
+        for d, (start, _stop), n in zip(v.dims, bounds, shape):
+            offsets[d] = offsets[d] + start
+            extents[d] = n
+        view = _View(v.ref, tuple(offsets), tuple(extents), v.dims)
+        return SymArray(shape, self.dtype, view, ctx)
 
     def __bool__(self):
         raise NonAffineError(
@@ -571,27 +599,6 @@ def _make_patches():
 
     patch(pl, "when", mk_when)
 
-    def mk_load(orig):
-        def load(ref, idx):
-            if isinstance(ref, _TracedRef):
-                return ref[idx]
-            return orig(ref, idx)
-
-        return load
-
-    patch(pl, "load", mk_load)
-
-    def mk_store(orig):
-        def store(ref, idx, val):
-            if isinstance(ref, _TracedRef):
-                ref[idx] = val
-                return None
-            return orig(ref, idx, val)
-
-        return store
-
-    patch(pl, "store", mk_store)
-
     # ---- jnp / lax surface ---------------------------------------------
     def mk_minmax(orig, clamp_attr):
         def minmax(a, b):
@@ -651,42 +658,6 @@ def _make_patches():
         return dot_general
 
     patch(jax.lax, "dot_general", mk_dot_general)
-
-    def mk_dynamic_slice(orig):
-        def dynamic_slice(operand, start_indices, slice_sizes):
-            if not _sym_args(operand, *start_indices):
-                return orig(operand, start_indices, slice_sizes)
-            ctx = _CTX
-            sizes = tuple(int(s) for s in slice_sizes)
-            if not isinstance(operand, SymArray):
-                raise TraceError(ctx.name, "dynamic_slice",
-                                 f"slice of untraced value {operand!r}")
-            if operand.view is None:
-                ctx.body.notes.append(
-                    "dynamic_slice of a derived (non-ref) array: per-point "
-                    "address expressions unavailable for it")
-                ctx.body.elementwise_elems += 0.0
-                return SymArray(sizes, operand.dtype, None, ctx)
-            v = operand.view
-            offsets = list(v.offsets)
-            extents = list(v.extents)
-            for axis, (start, size) in enumerate(zip(start_indices, sizes)):
-                d = v.dims[axis]
-                try:
-                    s = affine(start) if not isinstance(
-                        start, (int, np.integer)) else int(start)
-                except NonAffineError as e:
-                    raise TraceError(
-                        ctx.name, f"ref {v.ref.name!r}",
-                        f"non-affine dynamic_slice start: {e}") from e
-                offsets[d] = offsets[d] + s
-                extents[d] = size
-            nv = _View(v.ref, tuple(offsets), tuple(extents), v.dims)
-            return SymArray(nv.array_shape(), operand.dtype, nv, ctx)
-
-        return dynamic_slice
-
-    patch(jax.lax, "dynamic_slice", mk_dynamic_slice)
 
     def mk_unary(orig):
         def unary(x, *a, **kw):

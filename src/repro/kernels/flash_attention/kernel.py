@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_INTERPRET = True
+from repro.kernels import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -74,7 +75,7 @@ def make_flash_attention(
 
     def call(q, k, v):
         """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D)."""
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(B * Hq, Sq // bq, nk),
             in_specs=[
@@ -99,7 +100,7 @@ def make_flash_attention(
                 pltpu.VMEM((bq, 128), jnp.float32),
                 pltpu.VMEM((bq, 128), jnp.float32),
             ],
-            interpret=_INTERPRET,
+            dots=[(bq, bk), (bq, D)],
         )(q, k, v)
 
     return call
@@ -141,7 +142,7 @@ def make_flash_decode(B, Hq, Hkv, Skv, D, bk, dtype=jnp.float32, scale=None):
             o_ref[0, 0] = (acc[...] / jnp.maximum(l_s[:1, :1], 1e-30)).astype(o_ref.dtype)
 
     def call(q, k, v):
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(B * Hq, nk),
             in_specs=[
@@ -160,7 +161,7 @@ def make_flash_decode(B, Hq, Hkv, Skv, D, bk, dtype=jnp.float32, scale=None):
                 pltpu.VMEM((8, 128), jnp.float32),
                 pltpu.VMEM((8, 128), jnp.float32),
             ],
-            interpret=_INTERPRET,
+            dots=[(1, bk), (1, D)],
         )(q, k, v)
 
     return call

@@ -9,7 +9,8 @@ frontend (DESIGN §9); nothing here is hand-lowered:
 
   * ``rowstream`` — grid over rows; three row refs (y, y+1, y+2 of the
     padded plane) supply the y-halo, x-halo via static slices.  Per-point
-    affine accesses, so the frontend lowers it for the GPU backend too.
+    affine accesses, so the frontend lowers it for the GPU backend.  Its
+    one-row blocks do not tile on TPU, so the TPU space rejects it.
   * ``ytile``    — grid over y-tiles; two tile refs (j, j+1) supply the
     tile+halo rows via concatenation (the established tile+halo trick).
     Fewer grid steps, bigger blocks; y-halo rows are refetched.
@@ -20,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_INTERPRET = True
+from repro.kernels import pallas_call
 
 
 def make_rowstream(domain: tuple, weights, dtype=jnp.float32):
@@ -30,7 +31,7 @@ def make_rowstream(domain: tuple, weights, dtype=jnp.float32):
 
     def kernel(r0, r1, r2, o_ref):
         def sl(row, x0):
-            return jax.lax.dynamic_slice(row[0], (x0,), (X,))
+            return row[0, x0:x0 + X]
 
         # access order mirrors the canonical 2d5pt spec: center, up, down,
         # left, right
@@ -46,13 +47,12 @@ def make_rowstream(domain: tuple, weights, dtype=jnp.float32):
         in_specs = [
             pl.BlockSpec((1, Xp), lambda y, k=k: (y + k, 0)) for k in range(3)
         ]
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(Y,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, X), lambda y: (y, 0)),
             out_shape=jax.ShapeDtypeStruct((Y, X), dtype),
-            interpret=_INTERPRET,
         )(*([src_padded] * 3))
 
     return call
@@ -70,7 +70,7 @@ def make_ytile(domain: tuple, ty: int, weights, dtype=jnp.float32):
         rows = jnp.concatenate([a_ref[...], b_ref[...]], axis=0)
 
         def sl(y0, x0):
-            return jax.lax.dynamic_slice(rows, (y0, x0), (ty, X))
+            return rows[y0:y0 + ty, x0:x0 + X]
 
         o_ref[...] = wc * sl(1, 1) + wn * (sl(0, 1) + sl(2, 1)
                                            + sl(1, 0) + sl(1, 2))
@@ -78,7 +78,7 @@ def make_ytile(domain: tuple, ty: int, weights, dtype=jnp.float32):
     def call(src_padded_y):
         """src_padded_y: ((ny + 1) * ty, X + 2) — 1 halo row at the top,
         padded to a whole extra tile at the bottom (ops.py prepares it)."""
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(ny,),
             in_specs=[
@@ -87,7 +87,6 @@ def make_ytile(domain: tuple, ty: int, weights, dtype=jnp.float32):
             ],
             out_specs=pl.BlockSpec((ty, X), lambda j: (j, 0)),
             out_shape=jax.ShapeDtypeStruct((Y, X), dtype),
-            interpret=_INTERPRET,
         )(src_padded_y, src_padded_y)
 
     return call

@@ -19,39 +19,31 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import pallas_call
 
 from .ref import VELOCITIES, WEIGHTS
 
-_INTERPRET = True
 
+def _compute(pdf_tap, phase_tap, o_ref, tau, kappa):
+    """Shared collide+stream math, stored PDF by PDF into ``o_ref[q, 0]``.
 
-def _compute(planes_q, phase_m, phase_c, phase_p, Y, X, y0, tau, kappa):
-    """Shared collide+stream math on padded (rows, Xp) planes.
-
-    planes_q[q]: padded plane of PDF q already at the right z (pull).
-    phase_m/c/p: phase planes at z-1, z, z+1.
-    y0: row offset of the output origin inside the padded planes.
-    Returns (15, Y, X) new PDFs.
+    ``pdf_tap(q, dy, dx)``: the output-sized window of PDF q's padded
+    plane (already at the right z, pull scheme) shifted by (dy, dx).
+    ``phase_tap(k, dy, dx)``: the same window of the phase plane at z-1+k.
     """
-
-    def sl(a, dy, dx):
-        return jax.lax.dynamic_slice(a, (y0 + dy, 1 + dx), (Y, X))
-
-    phi = sl(phase_c, 0, 0)
-    gx = 0.5 * (sl(phase_c, 0, 1) - sl(phase_c, 0, -1))
-    gy = 0.5 * (sl(phase_c, 1, 0) - sl(phase_c, -1, 0))
-    gz = 0.5 * (sl(phase_p, 0, 0) - sl(phase_m, 0, 0))
+    phi = phase_tap(1, 0, 0)
+    gx = 0.5 * (phase_tap(1, 0, 1) - phase_tap(1, 0, -1))
+    gy = 0.5 * (phase_tap(1, 1, 0) - phase_tap(1, -1, 0))
+    gz = 0.5 * (phase_tap(2, 0, 0) - phase_tap(0, 0, 0))
     inv = jax.lax.rsqrt(gx * gx + gy * gy + gz * gz + 1e-12)
     sharp = kappa * phi * (1.0 - phi)
-    out = []
     for qi, (cx, cy, cz) in enumerate(VELOCITIES):
         w = WEIGHTS[qi]
-        h = sl(planes_q[qi], -cy, -cx)
+        h = pdf_tap(qi, -cy, -cx)
         cdotn = (cx * gx + cy * gy + cz * gz) * inv
         heq = w * phi + w * sharp * cdotn
-        out.append(h - (h - heq) / tau)
-    return jnp.stack(out)
+        o_ref[qi, 0] = h - (h - heq) / tau
 
 
 def make_replane(domain: tuple, tau: float = 0.8, kappa: float = 0.15, dtype=jnp.float32):
@@ -60,12 +52,16 @@ def make_replane(domain: tuple, tau: float = 0.8, kappa: float = 0.15, dtype=jnp
 
     def kernel(*refs):
         pdf_refs = refs[:15]
-        ph_m, ph_c, ph_p = refs[15:18]
+        phases = refs[15:18]
         o_ref = refs[18]
-        planes = [pdf_refs[q][0, 0] for q in range(15)]
-        o_ref[:, 0] = _compute(
-            planes, ph_m[0], ph_c[0], ph_p[0], Y, X, 1, tau, kappa
-        )
+
+        def pdf_tap(q, dy, dx):
+            return pdf_refs[q][0, 0, 1 + dy:1 + dy + Y, 1 + dx:1 + dx + X]
+
+        def phase_tap(k, dy, dx):
+            return phases[k][0, 1 + dy:1 + dy + Y, 1 + dx:1 + dx + X]
+
+        _compute(pdf_tap, phase_tap, o_ref, tau, kappa)
 
     def call(pdf_padded, phase_padded):
         """pdf_padded (15, Z+2, Yp, Xp), phase_padded (Z+2, Yp, Xp)."""
@@ -81,13 +77,12 @@ def make_replane(domain: tuple, tau: float = 0.8, kappa: float = 0.15, dtype=jnp
             in_specs.append(
                 pl.BlockSpec((1, Yp, Xp), functools.partial(lambda k, t: (t + k, 0, 0), k))
             )
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(Z,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((15, 1, Y, X), lambda t: (0, t, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((15, Z, Y, X), dtype),
-            interpret=_INTERPRET,
         )(*([pdf_padded] * 15 + [phase_padded] * 3))
 
     return call
@@ -108,13 +103,16 @@ def make_ytile(domain: tuple, ty: int, tau: float = 0.8, kappa: float = 0.15, dt
         pdf_b = refs[15:30]
         ph = refs[30:36]  # (m_a, m_b, c_a, c_b, p_a, p_b)
         o_ref = refs[36]
-        planes = [
-            jnp.concatenate([pdf_a[q][0, 0], pdf_b[q][0, 0]], axis=0) for q in range(15)
-        ]
-        ph_m = jnp.concatenate([ph[0][0], ph[1][0]], axis=0)
-        ph_c = jnp.concatenate([ph[2][0], ph[3][0]], axis=0)
-        ph_p = jnp.concatenate([ph[4][0], ph[5][0]], axis=0)
-        o_ref[:, 0] = _compute(planes, ph_m, ph_c, ph_p, ty, X, 1, tau, kappa)
+        # tile j and tile j+1 stacked hold the tile plus its halo rows
+        def window(a, b, dy, dx):
+            rows = jnp.concatenate([a, b], axis=0)
+            return rows[1 + dy:1 + dy + ty, 1 + dx:1 + dx + X]
+
+        phases = [(ph[2 * k][0], ph[2 * k + 1][0]) for k in range(3)]
+        _compute(
+            lambda q, dy, dx: window(pdf_a[q][0, 0], pdf_b[q][0, 0], dy, dx),
+            lambda k, dy, dx: window(*phases[k], dy, dx),
+            o_ref, tau, kappa)
 
     def call(pdf_padded, phase_padded):
         """pdf_padded (15, Z+2, (ny+1)*ty, Xp), phase same y alloc."""
@@ -138,13 +136,12 @@ def make_ytile(domain: tuple, ty: int, tau: float = 0.8, kappa: float = 0.15, dt
                     )
                 )
         args = [pdf_padded] * 30 + [phase_padded] * 6
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(ny, Z),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((15, 1, ty, X), lambda j, t: (0, t, j, 0)),
             out_shape=jax.ShapeDtypeStruct((15, Z, Y, X), dtype),
-            interpret=_INTERPRET,
         )(*args)
 
     return call

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_INTERPRET = True
+from repro.kernels import pallas_call
 
 
 def make_matmul(M, K, N, bm, bk, bn, dtype=jnp.float32, out_dtype=None):
@@ -39,7 +39,7 @@ def make_matmul(M, K, N, bm, bk, bn, dtype=jnp.float32, out_dtype=None):
             o_ref[...] = acc[...].astype(o_ref.dtype)
 
     def call(a, b):
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(M // bm, N // bn, nk),
             in_specs=[
@@ -49,7 +49,7 @@ def make_matmul(M, K, N, bm, bk, bn, dtype=jnp.float32, out_dtype=None):
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            interpret=_INTERPRET,
+            dots=[(bm, bn)],
         )(a, b)
 
     return call

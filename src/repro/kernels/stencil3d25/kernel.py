@@ -17,31 +17,34 @@ All variants keep x/y halos in-plane via static slices of padded planes.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
 
-def _apply_star(plane_at, weights, r, Y, X, y0, x0):
-    """Weighted star sum given ``plane_at(dz) -> padded (yrows, Xp) plane``.
 
-    y0/x0: offsets of the output origin inside the padded plane.
+def _apply_star(plane, weights, r, Y, X, y0, x0):
+    """Weighted star sum.  ``plane(dz) -> (ref, lead)`` names the ref and
+    leading index holding the padded plane at z-offset dz; every tap is a
+    static (Y, X) window sliced straight off that ref, its origin at
+    (y0, x0) of the padded plane plus the tap's in-plane shift.
     """
-    out = weights[0] * jax.lax.dynamic_slice(plane_at(0), (y0, x0), (Y, X))
+
+    def tap(dz, dy, dx):
+        ref, lead = plane(dz)
+        return ref[lead, y0 + dy:y0 + dy + Y, x0 + dx:x0 + dx + X]
+
+    out = weights[0] * tap(0, 0, 0)
     w = 1
     for axis in range(3):
         for o in range(1, r + 1):
             for s in (-o, o):
-                if axis == 0:
-                    sl = jax.lax.dynamic_slice(plane_at(s), (y0, x0), (Y, X))
-                elif axis == 1:
-                    sl = jax.lax.dynamic_slice(plane_at(0), (y0 + s, x0), (Y, X))
-                else:
-                    sl = jax.lax.dynamic_slice(plane_at(0), (y0, x0 + s), (Y, X))
-                out = out + weights[w] * sl
+                shift = [0, 0, 0]
+                shift[axis] = s
+                out = out + weights[w] * tap(*shift)
                 w += 1
     return out
 
@@ -56,23 +59,20 @@ def make_replane(r: int, domain: tuple, weights, dtype=jnp.float32):
         planes = refs[: 2 * r + 1]
         o_ref = refs[2 * r + 1]
 
-        def plane_at(dz):
-            return planes[dz + r][0]
-
-        o_ref[0] = _apply_star(plane_at, weights, r, Y, X, r, r)
+        o_ref[0] = _apply_star(lambda dz: (planes[dz + r], 0),
+                               weights, r, Y, X, r, r)
 
     def call(src_padded):
         in_specs = [
             pl.BlockSpec((1, Yp, Xp), functools.partial(lambda k, t: (t + k, 0, 0), k))
             for k in range(2 * r + 1)
         ]
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(Z,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Y, X), lambda t: (t, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((Z, Y, X), dtype),
-            interpret=_INTERPRET,
         )(*([src_padded] * (2 * r + 1)))
 
     return call
@@ -92,14 +92,12 @@ def make_ring(r: int, domain: tuple, weights, dtype=jnp.float32):
 
         @pl.when(t >= 2 * r)
         def _():
-            def plane_at(dz):
-                # center plane is t - r (padded z coords); slot modulo ring
-                return ring[(t - r + dz) % nring]
-
-            o_ref[0] = _apply_star(plane_at, weights, r, Y, X, r, r)
+            # center plane is t - r (padded z coords); slot modulo ring
+            o_ref[0] = _apply_star(lambda dz: (ring, (t - r + dz) % nring),
+                                   weights, r, Y, X, r, r)
 
     def call(src_padded):
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(Zp,),
             in_specs=[pl.BlockSpec((1, Yp, Xp), lambda t: (t, 0, 0))],
@@ -108,7 +106,6 @@ def make_ring(r: int, domain: tuple, weights, dtype=jnp.float32):
             ),
             out_shape=jax.ShapeDtypeStruct((Z, Y, X), dtype),
             scratch_shapes=[pltpu.VMEM((nring, Yp, Xp), dtype)],
-            interpret=_INTERPRET,
         )(src_padded)
 
     return call
@@ -134,15 +131,13 @@ def make_ytile_ring(r: int, domain: tuple, weights, ty: int, dtype=jnp.float32):
 
         @pl.when(t >= 2 * r)
         def _():
-            def plane_at(dz):
-                return ring[(t - r + dz) % nring]
-
-            o_ref[0] = _apply_star(plane_at, weights, r, ty, X, r, r)
+            o_ref[0] = _apply_star(lambda dz: (ring, (t - r + dz) % nring),
+                                   weights, r, ty, X, r, r)
 
     def call(src_padded_y):
         """src_padded_y: (Zp, y_alloc, Xp) — y padded by r at top and to
         y_alloc at the bottom (ops.py prepares this)."""
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(ny, Zp),
             in_specs=[
@@ -154,15 +149,9 @@ def make_ytile_ring(r: int, domain: tuple, weights, ty: int, dtype=jnp.float32):
             ),
             out_shape=jax.ShapeDtypeStruct((Z, Y, X), dtype),
             scratch_shapes=[pltpu.VMEM((nring, 2 * ty, Xp), dtype)],
-            interpret=_INTERPRET,
         )(src_padded_y, src_padded_y)
 
     return call
-
-
-# interpret=True: this container validates kernels on CPU; on a real TPU
-# deployment flip to False (module-level so tests/benches share it).
-_INTERPRET = True
 
 
 VARIANTS = ("replane", "ring", "ytile_ring")

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_INTERPRET = True
+from repro.kernels import pallas_call
 
 
 def make_transpose(M: int, N: int, bm: int, bn: int, dtype=jnp.float32):
@@ -26,13 +26,12 @@ def make_transpose(M: int, N: int, bm: int, bn: int, dtype=jnp.float32):
         o_ref[...] = jnp.transpose(x_ref[...])
 
     def call(x):
-        return pl.pallas_call(
+        return pallas_call(
             kernel,
             grid=(M // bm, N // bn),
             in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
             out_specs=pl.BlockSpec((bn, bm), lambda i, j: (j, i)),
             out_shape=jax.ShapeDtypeStruct((N, M), dtype),
-            interpret=_INTERPRET,
         )(x)
 
     return call

@@ -383,14 +383,19 @@ def test_random_affine_index_map_roundtrip(data):
         d for d in range(ngrid) if any(cs[d] for cs in coeffs)))
     assert x_op.grid_deps == expected_deps
     assert x_op.block_shape == block
-    # fetch structure: closed form over traced deps == explicit grid walk
-    assert fetch_count(grid, x_op.grid_deps) == \
-        fetch_count_oracle(grid, index_map)
+    # fetch structure: the traced operand's count == explicit grid walk;
+    # the closed form over traced deps stands unless two grid dims step
+    # one block coordinate
+    walked = fetch_count_oracle(grid, index_map)
+    assert hbm_traffic(traced_spec)[1]["x"]["fetches"] == walked
+    closed = fetch_count(grid, x_op.grid_deps)
+    assert x_op.fetches == (None if closed == walked else walked)
     # volumes/footprints: traced spec == direct construction
     direct = PallasKernelSpec(
         name=traced_spec.name, grid=grid,
         operands=(
-            OperandSpec("x", block, 4, grid_deps=expected_deps),
+            OperandSpec("x", block, 4, grid_deps=expected_deps,
+                        fetches=None if closed == walked else walked),
             traced_spec.operands[1],
         ),
         work_per_step=traced_spec.work_per_step,
@@ -444,6 +449,32 @@ def test_body_negative_indices_normalize():
     assert traced.body.ok
     load = traced.body.loads("op")[0]
     assert load.offsets == (1, 0) and load.extents == (1, 8)
+
+
+def test_body_value_slice_narrows_the_load():
+    """A static slice of a loaded value records the narrower ref window;
+    a slice of a derived value is noted as such (no per-point address)."""
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        rows = jnp.concatenate([x_ref[...], x_ref[...]], axis=0)
+        o_ref[...] = x_ref[...][1:5, 2:10] + rows[3:7, 0:8]
+
+    def call(x):
+        return pl.pallas_call(
+            kernel,
+            grid=(4,),
+            in_specs=[pl.BlockSpec((8, 16), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((4, 8), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((16, 8), jnp.float32),
+            interpret=True,
+        )(x)
+
+    traced = trace_kernel(call, [arg("x", (32, 16))], name="valueslice",
+                          trace_body=True, require_body=True)
+    windows = {(a.offsets, a.extents) for a in traced.body.loads("op")}
+    assert ((1, 2), (4, 8)) in windows
+    assert traced.body.notes
 
 
 def test_body_scalar_where_on_predicate():

@@ -118,6 +118,18 @@ def test_reject_scratch_staged_gpu_lowering():
         lower_gpu(traced)
 
 
+def test_untileable_blocks_leave_the_tpu_space():
+    """One-row blocks of a 2D array do not tile on TPU: the jacobi
+    rowstream candidate is rejected with the reason, y-tiles stay."""
+    from repro.kernels.jacobi2d.generator import candidate_specs
+
+    cands = dict((c["variant"] + str(c.get("ty", "")), s)
+                 for c, s in candidate_specs((64, 256), 4))
+    assert isinstance(cands["rowstream"], RejectedSpec)
+    assert "does not tile on TPU" in cands["rowstream"].reason
+    assert not isinstance(cands["ytile8"], RejectedSpec)
+
+
 def test_price_kernel_reports_gpu_rejection():
     """A TPU-only-traceable kernel still prices on TPU; the GPU machines get
     the tracer's diagnostic as their skip reason."""

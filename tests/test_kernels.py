@@ -78,6 +78,22 @@ def test_flash_attention_sweep(gqa, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3)
 
 
+@pytest.mark.parametrize("call,err", [
+    ("matmul", RuntimeError),       # no 128-multiple blocking of 100
+    ("flash", ValueError),          # S not a multiple of 128
+])
+def test_entry_points_raise_instead_of_substituting(call, err):
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.matmul.ops import tuned_matmul
+
+    x = jnp.ones((1, 2, 100, 64))
+    with pytest.raises(err):
+        if call == "matmul":
+            tuned_matmul(x[0, 0], x[0, 0].T)
+        else:
+            flash_attention(x, x, x)
+
+
 @pytest.mark.parametrize("bk", [128, 256])
 def test_flash_decode(bk):
     B, Hq, Hkv, S, D = 2, 8, 2, 512, 64

@@ -1,17 +1,22 @@
 """TPU estimator tests: revisit analysis, feasibility, config selection."""
 import random
 
+import pytest
 from hypothesis_compat import given, settings, st  # skips property tests without hypothesis
 
-from repro.core.machines import TPUMachine, TPU_V5E
+from repro.core.machines import TPUMachine, TPU_V5E, machine_for_device
 from repro.core.tpu_adapt import (
+    VMEM_BASE_RESERVE_BYTES,
     MatmulShape,
     OperandSpec,
     PallasKernelSpec,
     estimate_pallas,
     fetch_count,
     fetch_count_oracle,
+    linear_fetch_count,
     select_pallas_config,
+    vmem_limit_bytes,
+    vmem_reserve,
 )
 
 
@@ -56,6 +61,62 @@ def test_layer_condition_feasibility():
         operands=(OperandSpec("x", (1, 128, 128), 4, grid_deps=(0,)),),
     )
     assert estimate_pallas(small).feasible
+    # 100 MiB of double-buffered blocks fit 128 MiB, but not with the
+    # compiler's reserve (a copy of the 50 MiB input block) beside them
+    near = PallasKernelSpec(
+        name="near", grid=(4,),
+        operands=(OperandSpec("x", (1, 12800, 1024), 4, grid_deps=(0,)),),
+    )
+    assert not estimate_pallas(near).feasible
+    # a feasible kernel's Mosaic limit grants the footprint plus the reserve
+    est = estimate_pallas(small)
+    assert vmem_limit_bytes(small.operands, 0) == \
+        est.vmem_alloc_bytes + est.detail["vmem_reserve"]
+
+
+def test_vmem_reserve_covers_inputs_or_dot_results():
+    MiB = 1 << 20
+    a = OperandSpec("a", (1024, 256), 2)
+    b = OperandSpec("b", (256, 1024), 2)
+    o = OperandSpec("o", (1024, 1024), 2, is_output=True)
+    # 1 MiB of input blocks and 2 MiB of scratch, no dots: one copy of
+    # what the body reads
+    assert vmem_reserve((a, b, o), 2 * MiB, (), TPU_V5E) == \
+        VMEM_BASE_RESERVE_BYTES + 3 * MiB
+    # the (1024, 1024) f32 dot result (4 MiB) outweighs them
+    assert vmem_reserve((a, b, o), 2 * MiB, [(1024, 1024)], TPU_V5E) == \
+        VMEM_BASE_RESERVE_BYTES + 4 * MiB
+    # the estimator and the kernel's limit count the same dots
+    spec = PallasKernelSpec(
+        name="mm", grid=(8, 8, 32), operands=(a, b, o),
+        matmuls_per_step=(MatmulShape(1024, 256, 1024),),
+        scratch_bytes=2 * MiB, elem_bytes=2)
+    est = estimate_pallas(spec)
+    assert est.detail["vmem_reserve"] == VMEM_BASE_RESERVE_BYTES + 4 * MiB
+    assert vmem_limit_bytes(spec.operands, 2 * MiB, [(1024, 1024)]) == \
+        est.vmem_alloc_bytes + est.detail["vmem_reserve"]
+
+
+@pytest.mark.parametrize("grid,coeffs,walked", [
+    ((2, 2), [[1, 1]], 3),              # (i + j,): (1, 0) repeats (0, 1)
+    ((3, 4), [[4, 1]], 12),             # 4i + j: every step moves
+    ((3, 4), [[1, 1], [0, 1]], 12),     # j alone tells the steps apart
+    ((3, 2), [[1, 1]], 4),              # i + j on a longer grid
+    ((2, 2, 2), [[1, 0, 1]], 7),        # i + k: (1, 0, 0) repeats (0, 1, 1)
+    ((2, 5), [[0, 0]], 1),              # constant map
+])
+def test_linear_fetch_count_matches_walk(grid, coeffs, walked):
+    def index_map(*g):
+        return tuple(sum(c * x for c, x in zip(row, g)) for row in coeffs)
+
+    assert fetch_count_oracle(grid, index_map) == walked
+    assert linear_fetch_count(grid, coeffs) == walked
+
+
+def test_device_kind_table():
+    assert machine_for_device("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="no machine model"):
+        machine_for_device("TPU v4")
 
 
 def test_stencil_selector_prefers_ring_until_lc_breaks():
