@@ -315,13 +315,9 @@ class Explorer:
                  cache_path: str | None = None, strict: bool = False,
                  cache_max_entries: int | None = None,
                  cache_max_bytes: int | None = None,
-                 trace_out: str | None = None,
                  resume: str | os.PathLike | None = None):
         self.parallel = parallel
         self.max_workers = max_workers
-        self.trace_out = trace_out
-        if trace_out:
-            obs.enable()
         # crash-consistent sweeps (DESIGN.md §15): completed cells journal
         # to ``resume`` as they finish, and a later Explorer pointed at the
         # same path restores them instead of re-pricing
@@ -520,8 +516,6 @@ class Explorer:
             with obs.span("engine.bound_rank", kind="degraded",
                           cells=len(cells)):
                 report = self._bound_sweep(cells, top_k)
-            if self.trace_out:
-                obs.write_trace(self.trace_out)
         for w, m, reason in undefined:
             report.skipped.append(
                 SkippedConfig(w.name, m.name, None, reason))
@@ -624,8 +618,6 @@ class Explorer:
                 report = self._sweep_impl(cells, strict=strict, top_k=top_k,
                                           progress=progress,
                                           machine_axis=machine_axis)
-            if self.trace_out:
-                obs.write_trace(self.trace_out)
             return report
 
     def _sweep_impl(self, cells, *, strict: bool | None = None,

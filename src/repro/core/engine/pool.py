@@ -91,20 +91,23 @@ def _pool_batch(calls: Sequence[tuple], ctx: tuple | None = None):
 
     ``ctx`` is the parent's telemetry context (``obs.current_context()``),
     shipped through task metadata under the same fork/spawn discipline as
-    the fault plan.  When present, the chunk runs under a ``pool.chunk``
-    child span and returns ``("obs", outcomes, records)`` so the parent can
-    merge the worker's spans into its timeline; when absent (telemetry
-    disabled) the return shape is the plain outcome list, unchanged.
+    the fault plan.  When present, the chunk records on an ``obs.Scope``
+    of that context whose root is the ``pool.chunk`` span, and returns
+    ``("obs", outcomes, records)`` so the parent can merge the worker's
+    spans into its timeline; the scope ends with the chunk, so a
+    persistent worker records nothing for the chunks that follow.  When
+    absent (telemetry disabled) the return shape is the plain outcome
+    list, unchanged.
     """
     faults.ensure_env_plan()
     faults.crash_point("pool.worker_crash")
     faults.hang_point("pool.worker_hang")
     if ctx is None:
         return guarded_batch(calls)
-    obs.adopt(ctx)
-    with obs.span("pool.chunk", "pool", tasks=len(calls)):
+    scope = obs.Scope(ctx)
+    with scope.active():
         out = guarded_batch(calls)
-    return ("obs", out, obs.drain())
+    return ("obs", out, scope.close("pool.chunk", "pool", tasks=len(calls)))
 
 
 def default_workers() -> int:

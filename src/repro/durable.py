@@ -32,9 +32,11 @@ lying-filesystem model — and ``proc.kill`` (a SIGKILL
 :func:`repro.faults.kill_point`) fires after each commit, so plans can die
 at exact journal indices.
 
-This module depends only on the stdlib and :mod:`repro.faults` so every
-layer (obs, benchmarks, engine, serve) can use it without import cycles;
-telemetry spans around recovery/compaction live at the call sites.
+This module depends only on the stdlib, :mod:`repro.faults` and
+:mod:`repro.obs` (which imports it only lazily), so every layer
+(benchmarks, engine, serve) can use it without import cycles.  Each
+append is a ``durable.append`` span carrying its ``bytes`` and
+``fsync_us``; spans around recovery/compaction live at the call sites.
 """
 from __future__ import annotations
 
@@ -42,8 +44,9 @@ import hashlib
 import os
 import struct
 import tempfile
+import time
 
-from repro import faults
+from repro import faults, obs
 
 FRAME_MAGIC = b"RJ1\x00"
 _HEADER = struct.Struct("<4sI32s")     # magic, payload length, sha256
@@ -173,10 +176,13 @@ class Journal:
             data = data[:max(1, len(data) // 2)]
         d = os.path.dirname(os.path.abspath(self.path))
         os.makedirs(d, exist_ok=True)
-        with open(self.path, "ab") as f:
+        with obs.span("durable.append", "durable", bytes=len(data)) as sp, \
+                open(self.path, "ab") as f:
             f.write(data)
             f.flush()
+            t0 = time.perf_counter_ns()
             os.fsync(f.fileno())
+            sp.add(fsync_us=(time.perf_counter_ns() - t0) / 1e3)
         index = self.appended
         self.appended += 1
         faults.kill_point("proc.kill")

@@ -30,7 +30,8 @@ import warnings
 from dataclasses import dataclass
 
 from .affine import AffineExpr, NonAffineError, Sym, affine
-from .candidates import KernelBuild, candidates, grid_space
+from .candidates import (KernelBuild, candidates, grid_space,
+                         traced_candidates)
 from .lower import CostModel, derive_costs, lower_gpu, lower_tpu
 from .trace import Placeholder, TraceError, TracedKernel, arg, trace_kernel
 
@@ -93,7 +94,7 @@ def price_kernel(call_fn, args, machines, *, name: str = "kernel",
 
 __all__ = [
     "AffineExpr", "NonAffineError", "Sym", "affine",
-    "KernelBuild", "candidates", "grid_space",
+    "KernelBuild", "candidates", "grid_space", "traced_candidates",
     "CostModel", "derive_costs", "lower_gpu", "lower_tpu",
     "Placeholder", "TraceError", "TracedKernel", "arg", "trace_kernel",
     "TracedSpecPayload", "trace_payload", "price_kernel",

@@ -19,6 +19,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Iterable, Iterator
 
+from repro import obs
 from repro.core.engine.protocol import RejectedSpec
 from repro.core.machines import TPU_V5E
 
@@ -73,6 +74,17 @@ def candidates(build: Callable, space: Iterable,
             yield config, RejectedSpec(kb.name, untileable)
             continue
         yield config, spec
+
+
+def traced_candidates(make: Callable, *key) -> tuple:
+    """``make(*key)``, a generator's cached tuple of :func:`candidates`
+    pairs, as one ``frontend.candidates`` span (its ``candidates``: pairs
+    made): the trace-and-lower loop with the generator's own set-up
+    around it, a cache hit included."""
+    with obs.span("frontend.candidates", "frontend") as sp:
+        out = make(*key)
+        sp.add(candidates=len(out))
+    return out
 
 
 def _untileable_block(traced, machine=TPU_V5E) -> str | None:

@@ -88,8 +88,12 @@ def use_compile_cache(checkout: str) -> str:
 
 
 def pallas_call(kernel, *, grid, in_specs, out_specs, out_shape,
-                scratch_shapes=(), dots=()):
+                scratch_shapes=(), dots=(), name: str | None = None):
     """``pl.pallas_call`` for this package's kernels.
+
+    ``name`` (``<family>_<variant>``, e.g. ``stencil3d25_ring``) names the
+    kernel in the compiled program, so a profiler trace tells variants
+    apart; the frontend's tracer accepts and ignores it.
 
     Interpret mode is read from the platform when the kernel is built, and
     Mosaic's VMEM limit is sized as the estimator's feasibility check
@@ -123,7 +127,8 @@ def pallas_call(kernel, *, grid, in_specs, out_specs, out_shape,
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, scratch_shapes=scratch_shapes,
-            interpret=interpret_mode(), compiler_params=params)(*args)
+            interpret=interpret_mode(), compiler_params=params,
+            name=name)(*args)
 
     return call
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+from repro.frontend.candidates import traced_candidates
 from repro.kernels import dtype_for
 from repro.core.machines import TPUMachine, TPU_V5E
 from repro.core.tpu_adapt import MatmulShape, pow2_tiles, select_pallas_config
@@ -59,7 +60,8 @@ def _candidates(B, Hq, Hkv, Sq, Skv, D, causal, elem_bytes) -> tuple:
 
 
 def candidate_specs(B, Hq, Hkv, Sq, Skv, D, causal=True, elem_bytes=2):
-    yield from _candidates(B, Hq, Hkv, Sq, Skv, D, bool(causal), elem_bytes)
+    yield from traced_candidates(_candidates, B, Hq, Hkv, Sq, Skv, D,
+                                 bool(causal), elem_bytes)
 
 
 def rank_configs(B, Hq, Hkv, Sq, Skv, D, causal=True, machine: TPUMachine = TPU_V5E,

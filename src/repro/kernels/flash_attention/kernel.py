@@ -77,6 +77,7 @@ def make_flash_attention(
         """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D)."""
         return pallas_call(
             kernel,
+            name="flash_attention_fwd",
             grid=(B * Hq, Sq // bq, nk),
             in_specs=[
                 pl.BlockSpec(
@@ -144,6 +145,7 @@ def make_flash_decode(B, Hq, Hkv, Skv, D, bk, dtype=jnp.float32, scale=None):
     def call(q, k, v):
         return pallas_call(
             kernel,
+            name="flash_attention_decode",
             grid=(B * Hq, nk),
             in_specs=[
                 pl.BlockSpec((1, 1, 1, D), lambda h, kb: (h // Hq, h % Hq, 0, 0)),

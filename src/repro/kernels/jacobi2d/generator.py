@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+from repro.frontend.candidates import traced_candidates
 from repro.kernels import dtype_for
 from repro.core.machines import TPUMachine, TPU_V5E
 from repro.core.tpu_adapt import select_pallas_config
@@ -58,7 +59,7 @@ def _candidates(domain: tuple, elem_bytes: int) -> tuple:
 
 
 def candidate_specs(domain: tuple, elem_bytes: int = 4):
-    yield from _candidates(tuple(domain), elem_bytes)
+    yield from traced_candidates(_candidates, tuple(domain), elem_bytes)
 
 
 @lru_cache(maxsize=None)

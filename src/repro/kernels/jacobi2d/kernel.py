@@ -49,6 +49,7 @@ def make_rowstream(domain: tuple, weights, dtype=jnp.float32):
         ]
         return pallas_call(
             kernel,
+            name="jacobi2d_rowstream",
             grid=(Y,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, X), lambda y: (y, 0)),
@@ -80,6 +81,7 @@ def make_ytile(domain: tuple, ty: int, weights, dtype=jnp.float32):
         padded to a whole extra tile at the bottom (ops.py prepares it)."""
         return pallas_call(
             kernel,
+            name="jacobi2d_ytile",
             grid=(ny,),
             in_specs=[
                 pl.BlockSpec((ty, Xp), lambda j: (j, 0)),

@@ -79,6 +79,7 @@ def make_replane(domain: tuple, tau: float = 0.8, kappa: float = 0.15, dtype=jnp
             )
         return pallas_call(
             kernel,
+            name="lbm_d3q15_replane",
             grid=(Z,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((15, 1, Y, X), lambda t: (0, t, 0, 0)),
@@ -138,6 +139,7 @@ def make_ytile(domain: tuple, ty: int, tau: float = 0.8, kappa: float = 0.15, dt
         args = [pdf_padded] * 30 + [phase_padded] * 6
         return pallas_call(
             kernel,
+            name="lbm_d3q15_ytile",
             grid=(ny, Z),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((15, 1, ty, X), lambda j, t: (0, t, j, 0)),

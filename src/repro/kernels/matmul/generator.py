@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+from repro.frontend.candidates import traced_candidates
 from repro.kernels import dtype_for
 from repro.core.machines import TPUMachine, TPU_V5E
 from repro.core.tpu_adapt import pow2_tiles, select_pallas_config
@@ -58,7 +59,7 @@ def _candidates(M, K, N, elem_bytes) -> tuple:
 
 
 def candidate_specs(M, K, N, elem_bytes=2):
-    yield from _candidates(M, K, N, elem_bytes)
+    yield from traced_candidates(_candidates, M, K, N, elem_bytes)
 
 
 def traced_gpu_spec(M, K, N, elem_bytes=2):

@@ -41,6 +41,7 @@ def make_matmul(M, K, N, bm, bk, bn, dtype=jnp.float32, out_dtype=None):
     def call(a, b):
         return pallas_call(
             kernel,
+            name="matmul_blocked",
             grid=(M // bm, N // bn, nk),
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

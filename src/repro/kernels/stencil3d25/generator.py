@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+from repro.frontend.candidates import traced_candidates
 from repro.kernels import dtype_for
 from repro.core.machines import TPUMachine, TPU_V5E
 from repro.core.tpu_adapt import RankedPallasConfig, select_pallas_config
@@ -83,7 +84,8 @@ def _candidates(r: int, domain: tuple, elem_bytes: int) -> tuple:
 
 def candidate_specs(r: int, domain: tuple, elem_bytes: int = 4):
     """Yield (config, PallasKernelSpec) for every generator decision."""
-    yield from _candidates(r, tuple(domain), elem_bytes)
+    yield from traced_candidates(_candidates, r, tuple(domain),
+                                 elem_bytes)
 
 
 def traced_gpu_spec(r: int, domain: tuple, elem_bytes: int = 8):

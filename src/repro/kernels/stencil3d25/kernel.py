@@ -69,6 +69,7 @@ def make_replane(r: int, domain: tuple, weights, dtype=jnp.float32):
         ]
         return pallas_call(
             kernel,
+            name="stencil3d25_replane",
             grid=(Z,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Y, X), lambda t: (t, 0, 0)),
@@ -99,6 +100,7 @@ def make_ring(r: int, domain: tuple, weights, dtype=jnp.float32):
     def call(src_padded):
         return pallas_call(
             kernel,
+            name="stencil3d25_ring",
             grid=(Zp,),
             in_specs=[pl.BlockSpec((1, Yp, Xp), lambda t: (t, 0, 0))],
             out_specs=pl.BlockSpec(
@@ -139,6 +141,7 @@ def make_ytile_ring(r: int, domain: tuple, weights, ty: int, dtype=jnp.float32):
         y_alloc at the bottom (ops.py prepares this)."""
         return pallas_call(
             kernel,
+            name="stencil3d25_ytile_ring",
             grid=(ny, Zp),
             in_specs=[
                 pl.BlockSpec((1, ty, Xp), lambda j, t: (t, j, 0)),

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
+from repro.frontend.candidates import traced_candidates
 from repro.kernels import dtype_for
 from repro.core.machines import TPUMachine, TPU_V5E
 from repro.core.tpu_adapt import pow2_tiles, select_pallas_config
@@ -55,8 +56,8 @@ def candidate_specs(shape: tuple, elem_bytes: int = 4, tile: int = 8):
     """(config, spec) pairs for transposing ``shape``, padded to ``tile``
     multiples (the kernel's operand is the padded array)."""
     M, N = shape
-    yield from _candidates(pad_to_tiles(M, tile), pad_to_tiles(N, tile),
-                           elem_bytes)
+    yield from traced_candidates(_candidates, pad_to_tiles(M, tile),
+                                 pad_to_tiles(N, tile), elem_bytes)
 
 
 @lru_cache(maxsize=None)

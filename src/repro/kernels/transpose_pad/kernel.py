@@ -28,6 +28,7 @@ def make_transpose(M: int, N: int, bm: int, bn: int, dtype=jnp.float32):
     def call(x):
         return pallas_call(
             kernel,
+            name="transpose_pad_tiled",
             grid=(M // bm, N // bn),
             in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
             out_specs=pl.BlockSpec((bn, bm), lambda i, j: (j, i)),

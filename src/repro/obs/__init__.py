@@ -6,16 +6,21 @@ replay, rate stage, pool chunks, scheduler, daemon ops), a documented
 metrics registry absorbing the historical scattered counters, and exporters
 (Chrome trace-event / Perfetto JSON, phase-time table, daemon ``trace`` op).
 
-Off by default; enable with any of
+Off by default; spans record with any of
 
   * ``REPRO_TRACE_OUT=trace.json`` in the environment — collection starts
     at import and the merged trace is written at interpreter exit;
-  * ``Explorer(trace_out="trace.json")`` — per-sweep dumps;
-  * ``obs.enable()`` programmatically.
+  * ``obs.enable()`` programmatically;
+  * a JAX profiler session capturing in the process — spans then record
+    as if enabled and are mirrored onto the profiler's timeline as
+    ``repro.<name>`` annotations;
+  * an ``obs.Scope`` active in the thread — one request's spans, which the
+    pricing daemon returns to a recording client in the result line.
 
-The disabled path costs one flag check per ``obs.span`` call site
-(<2% on the paper-grid cold sweep, gated by ``benchmarks/bench_obs.py``),
-and rankings are bitwise identical with telemetry on or off.
+The disabled path costs one flag check per ``obs.span`` call site, plus
+the profiler's own check once jax is loaded (<2% on the paper-grid cold
+sweep, gated by ``benchmarks/bench_obs.py``), and rankings are bitwise
+identical with telemetry on or off.
 """
 from __future__ import annotations
 
@@ -26,14 +31,15 @@ import os
 from . import metrics
 from .export import chrome_trace, summary, write_trace
 from .spans import (
+    Scope,
     SpanRecord,
-    adopt,
+    collect,
     current_context,
     disable,
-    drain,
     enable,
     enabled,
     ingest,
+    profiling,
     reset,
     span,
     spans,
@@ -58,6 +64,6 @@ if _env_out:
 
 __all__ = [
     "SpanRecord", "span", "enable", "disable", "enabled", "reset",
-    "spans", "drain", "ingest", "adopt", "current_context",
-    "chrome_trace", "write_trace", "summary", "metrics", "TRACE_ENV",
+    "spans", "ingest", "current_context", "profiling",
+    "Scope", "collect", "chrome_trace", "write_trace", "summary", "metrics", "TRACE_ENV",
 ]

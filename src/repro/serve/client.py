@@ -37,6 +37,7 @@ import socket
 import threading
 import time
 
+from repro import obs
 from repro.api import PriceRequest, PriceResult
 
 from .schema import SCHEMA_VERSION, decode, encode, request_digest
@@ -127,20 +128,29 @@ class PriceClient:
         return False
 
     # ---- wire plumbing -------------------------------------------------
+    @staticmethod
+    def _encode(payload: dict) -> bytes:
+        payload.setdefault("schema_version", SCHEMA_VERSION)
+        return (json.dumps(payload, separators=(",", ":")) + "\n").encode()
+
     def _send(self, payload: dict) -> None:
+        self._send_bytes(self._encode(payload))
+
+    def _send_bytes(self, data: bytes) -> None:
         if self._sock is None:
             raise OSError("client is closed")
-        payload.setdefault("schema_version", SCHEMA_VERSION)
-        data = (json.dumps(payload, separators=(",", ":")) + "\n").encode()
         with self._send_lock:
             self._sock.sendall(data)
 
-    def _recv(self) -> dict:
+    def _recv_line(self) -> bytes:
         line = self._rfile.readline()
         if not line:
             raise ServeError("daemon closed the connection",
                              error_class="ConnectionClosed")
-        return json.loads(line)
+        return line
+
+    def _recv(self) -> dict:
+        return json.loads(self._recv_line())
 
     def _take_id(self) -> int:
         with self._send_lock:
@@ -193,10 +203,31 @@ class PriceClient:
         order — a warm (memoized) answer arrives without waiting for cold
         sweeps submitted before it — and fires exactly once per request
         even across retries.
+
+        While this process records spans (DESIGN.md §14) the call is one
+        ``client.price`` span — ``client.encode``, ``client.wait`` (send
+        and the wait for each line), ``client.decode`` — and each request
+        carries its context, so the daemon's spans for it come back in
+        the result line and are ingested beneath.  They are parsed once
+        the call's spans are closed, so that cost stays out of them; the
+        daemon's time to render them, which the wait cannot help
+        including, is ``client.price``'s ``spans_us``.
         """
         if self._closed:
             raise OSError("client is closed")
         requests = list(requests)
+        shipped: list = []      # the daemon's spans of each line, as JSON
+        try:
+            with obs.span("client.price", "client",
+                          requests=len(requests)) as sp:
+                return self._price_many(requests, on_result, deadline_s, sp,
+                                        shipped)
+        finally:
+            for data in shipped:
+                obs.ingest(json.loads(data))
+
+    def _price_many(self, requests, on_result, deadline_s, sp,
+                    shipped) -> list:
         out: list = [None] * len(requests)
         done = [False] * len(requests)
         # digests key the retry: the server dedupes resubmissions on them
@@ -204,7 +235,8 @@ class PriceClient:
         attempt = 0
         while True:
             try:
-                self._attempt(requests, out, done, on_result, deadline_s)
+                self._attempt(requests, out, done, on_result, deadline_s,
+                              sp, shipped)
                 return out
             except (ServeError, OSError) as exc:
                 retryable = (isinstance(exc, OSError)
@@ -220,35 +252,56 @@ class PriceClient:
                     if attempt >= self.retries:
                         raise
 
-    def _attempt(self, requests, out, done, on_result, deadline_s) -> None:
+    def _attempt(self, requests, out, done, on_result, deadline_s,
+                 sp, shipped) -> None:
         """One submission round over the current connection: send every
-        still-unanswered request, then drain until each has an answer."""
+        still-unanswered request, then drain until each has an answer.
+        ``sp`` is the call's ``client.price`` span (the shared no-op when
+        nothing records): it counts the bytes each way, and the daemon's
+        spans of each line go to ``shipped``."""
         if self._sock is None:      # deferred or dropped connect
             self._connect()
         ids = {}
-        for i, request in enumerate(requests):
-            if done[i]:
-                continue
-            rid = self._take_id()
-            ids[rid] = i
-            msg = {"op": "price", "id": rid, "request": encode(request)}
-            if deadline_s is not None:
-                msg["deadline_s"] = deadline_s
-            self._send(msg)
+        ctx = obs.current_context() if sp.enabled else None
+        with obs.span("client.encode", "client"):
+            lines = []
+            for i, request in enumerate(requests):
+                if done[i]:
+                    continue
+                rid = self._take_id()
+                ids[rid] = i
+                msg = {"op": "price", "id": rid, "request": encode(request)}
+                if deadline_s is not None:
+                    msg["deadline_s"] = deadline_s
+                if ctx is not None:
+                    msg["obs"] = ctx
+                lines.append(self._encode(msg))
+        if sp.enabled:
+            sp.add(bytes_out=sp.args.get("bytes_out", 0)
+                   + sum(len(d) for d in lines))
         first_error = None
         while ids:
-            msg = self._recv()
-            rid = msg.get("id")
-            if rid not in ids:
-                continue            # e.g. an interleaved pong
-            i = ids.pop(rid)
-            if not msg.get("ok"):
-                err = _error_from(msg, "pricing failed")
-                if err.retryable:
-                    raise err       # resubmit the unanswered remainder
-                first_error = first_error or err
-                continue
-            result = decode(msg["result"])
+            with obs.span("client.wait", "client"):
+                for data in lines:
+                    self._send_bytes(data)
+                lines = ()
+                line = self._recv_line()
+            with obs.span("client.decode", "client"):
+                if sp.enabled:
+                    sp.add(bytes_in=sp.args.get("bytes_in", 0) + len(line))
+                    line = _split_spans(line, sp, shipped)
+                msg = json.loads(line)
+                rid = msg.get("id")
+                if rid not in ids:
+                    continue            # e.g. an interleaved pong
+                i = ids.pop(rid)
+                if not msg.get("ok"):
+                    err = _error_from(msg, "pricing failed")
+                    if err.retryable:
+                        raise err       # resubmit the unanswered remainder
+                    first_error = first_error or err
+                    continue
+                result = decode(msg["result"])
             out[i] = result
             done[i] = True
             if on_result is not None:
@@ -267,6 +320,22 @@ class PriceClient:
         if hinted:
             delay = max(delay, float(hinted))
         return min(delay, _MAX_BACKOFF_S)
+
+
+def _split_spans(line: bytes, sp, shipped: list) -> bytes:
+    """``line`` without its last keys, the daemon's ``spans`` (their JSON
+    goes to ``shipped``) and ``spans_us`` (added to ``sp``'s).  A quote
+    inside a JSON string is escaped, so the key's text is found only as
+    the key; the records carry no key of that name."""
+    at = line.rfind(b',"spans":')
+    if at < 0:
+        return line
+    tail = line[at + len(b',"spans":'):]
+    cut = tail.rfind(b',"spans_us":')
+    shipped.append(tail[:cut])
+    sp.add(spans_us=sp.args.get("spans_us", 0.0) + float(
+        tail[cut + len(b',"spans_us":'):].rstrip(b"}\r\n")))
+    return line[:at] + b"}"
 
 
 def _error_from(msg: dict, fallback: str) -> ServeError:

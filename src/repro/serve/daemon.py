@@ -8,11 +8,12 @@ message per line, every line carrying ``schema_version``.
 
 Client -> server ops:
     {"op": "price", "id": <any>, "request": <encoded PriceRequest>,
-     "deadline_s": <optional seconds>}
+     "deadline_s": <optional seconds>, "obs": <optional trace context>}
     {"op": "stats"} | {"op": "trace"} | {"op": "ping"} | {"op": "shutdown"}
 
 Server -> client lines:
-    {"ok": true, "op": "result", "id": ..., "digest": ..., "result": ...}
+    {"ok": true, "op": "result", "id": ..., "digest": ..., "result": ...,
+     "spans": [...], "spans_us": <the two only when the op carried "obs">}
     {"ok": true, "op": "stats"/"pong"/"bye", ...}
     {"ok": true, "op": "trace", "enabled": ..., "trace": <Chrome JSON>}
     {"ok": false, "id": ..., "error": "...", "error_class": "...",
@@ -22,6 +23,19 @@ Server -> client lines:
 ``obs.metrics`` snapshot; ``trace`` ships the daemon's collected span
 timeline as Chrome trace-event JSON (empty while telemetry is disabled —
 start with ``--trace-out`` or ``REPRO_TRACE_OUT`` to collect).
+
+Request-scoped spans (DESIGN.md §14): a ``price`` op that carries
+``"obs": [trace_id, parent span id]`` (``PriceClient`` sends its
+``obs.current_context()`` while it records) is recorded on a scope of its
+own — ``serve.request`` from the line read to the result written, with
+``serve.decode``, ``serve.queue``, the sweep's ``serve.price`` /
+``engine.*`` / ``durable.append`` subtree, ``serve.encode``, ``serve.send``
+and any ``gc.collect`` pause in between — and exactly those records come
+back as the result line's ``spans`` key, for the client to
+``obs.ingest``, followed by ``spans_us``, the microseconds it took to
+render them (after ``serve.request`` closed, yet inside the client's
+wait).  The daemon's own collection stays off; a line without the key
+is answered byte for byte as before.
 
 A connection may pipeline many ``price`` ops; results stream back **as
 they complete** (matched by ``id``, not by order) — a memo-hit answer for
@@ -52,6 +66,7 @@ import socket
 import socketserver
 import sys
 import threading
+import time
 
 from repro import durable, faults, obs
 from repro.core.engine import Explorer
@@ -71,7 +86,7 @@ class _Handler(socketserver.StreamRequestHandler):
         write_lock = threading.Lock()
         submitted: list = []    # futures owned by this connection
 
-        def send(payload: dict):
+        def sever() -> bool:
             if faults.drop_point("serve.socket_drop"):
                 # injected connection loss: sever this client mid-response
                 # (its retry path must recover; see bench_chaos_soak)
@@ -79,6 +94,14 @@ class _Handler(socketserver.StreamRequestHandler):
                     self.connection.shutdown(socket.SHUT_RDWR)
                 except OSError:
                     pass
+                return True
+            return False
+
+        def send(payload: dict, scope=None):
+            if scope is not None:
+                send_traced(_line(payload), scope)
+                return
+            if sever():
                 return
             data = _line(payload)
             with write_lock:
@@ -90,8 +113,35 @@ class _Handler(socketserver.StreamRequestHandler):
                     # the handler returned) — nobody is listening
                     pass
 
+        def send_traced(data: bytes, scope):
+            """Write the response line of a request that carried a trace
+            context: the line itself first (``serve.send``), then the
+            request's spans, ``serve.request`` last, appended as its
+            ``spans`` key, and the time their rendering took, as
+            ``spans_us``, before the newline."""
+            if sever():
+                scope.discard()
+                return
+            with write_lock:
+                try:
+                    with scope.active(), obs.span("serve.send", "serve"):
+                        self.wfile.write(data[:-2])     # all but "}\n"
+                        self.wfile.flush()
+                    records = scope.close("serve.request", "serve")
+                    t0 = time.perf_counter_ns()
+                    tail = b',"spans":' + json.dumps(
+                        records, separators=(",", ":"),
+                        default=str).encode()
+                    dump_us = (time.perf_counter_ns() - t0) / 1e3
+                    self.wfile.write(
+                        tail + f',"spans_us":{dump_us!r}}}\n'.encode())
+                    self.wfile.flush()
+                except (BrokenPipeError, OSError, ValueError):
+                    scope.discard()
+
         try:
             for raw in self.rfile:
+                t_read = time.perf_counter_ns()
                 raw = raw.strip()
                 if not raw:
                     continue
@@ -119,7 +169,8 @@ class _Handler(socketserver.StreamRequestHandler):
                         server.request_shutdown()
                         return
                     elif op == "price":
-                        self._price(server, msg, send, submitted)
+                        self._price(server, msg, send, send_traced,
+                                    submitted, t_read)
                     else:
                         send({"ok": False, "id": msg.get("id"),
                               "error": f"unknown op {op!r}",
@@ -131,8 +182,13 @@ class _Handler(socketserver.StreamRequestHandler):
                 if not fut.done():
                     server.scheduler.cancel(fut)
 
-    def _price(self, server, msg, send, submitted):
+    def _price(self, server, msg, send, send_traced, submitted, t_read):
         req_id = msg.get("id")
+        # a recording client's trace context: this request's spans are
+        # recorded on a scope of its own and return in its result line
+        ctx = msg.get("obs")
+        scope = (obs.Scope(ctx, t_read)
+                 if isinstance(ctx, list) and len(ctx) == 2 else None)
         try:
             version = msg.get("schema_version")
             if version != SCHEMA_VERSION:
@@ -146,36 +202,56 @@ class _Handler(socketserver.StreamRequestHandler):
         except Exception as exc:
             send({"ok": False, "id": req_id,
                   "error": f"{type(exc).__name__}: {exc}",
-                  "error_class": type(exc).__name__})
+                  "error_class": type(exc).__name__}, scope)
             return
+        if scope is not None:
+            # from the line read: json, schema decode and digest
+            scope.stamp("serve.decode", "serve", t_read,
+                        time.perf_counter_ns())
 
         def on_done(fut):
             if fut.cancelled():
+                if scope is not None:
+                    scope.discard()
                 return              # client already hung up
             try:
                 result = fut.result()
             except Exception as exc:
                 send({"ok": False, "id": req_id, "digest": digest,
                       "error": f"{type(exc).__name__}: {exc}",
-                      "error_class": type(exc).__name__})
+                      "error_class": type(exc).__name__}, scope)
                 return
-            # memoized wire rendering: warm answers re-send cached text
-            wire = server.scheduler.encoded(digest, result)
-            body = json.loads(wire)["body"]
-            send({"ok": True, "op": "result", "id": req_id,
-                  "digest": digest, "result": body})
+            if scope is None:
+                # memoized wire rendering: warm answers re-send cached text
+                wire = server.scheduler.encoded(digest, result)
+                body = json.loads(wire)["body"]
+                send({"ok": True, "op": "result", "id": req_id,
+                      "digest": digest, "result": body})
+                return
+            try:
+                with scope.active(), obs.span("serve.encode", "serve"):
+                    wire = server.scheduler.encoded(digest, result)
+                    body = json.loads(wire)["body"]
+                    data = _line({"ok": True, "op": "result", "id": req_id,
+                                  "digest": digest, "result": body})
+                send_traced(data, scope)
+            finally:
+                # a done-callback's exception is only logged: a scope left
+                # open would take every later collector pause
+                scope.discard()
 
         try:
             fut = server.scheduler.submit(request, digest,
-                                          deadline_s=deadline_s)
+                                          deadline_s=deadline_s,
+                                          scope=scope)
         except QueueFullError as exc:    # backpressure: explicit + retryable
             send({"ok": False, "id": req_id, "digest": digest,
                   "error": str(exc), "error_class": "QueueFullError",
-                  "retry_after_s": exc.retry_after_s})
+                  "retry_after_s": exc.retry_after_s}, scope)
             return
         except RuntimeError as exc:      # shutting down
             send({"ok": False, "id": req_id, "error": str(exc),
-                  "error_class": type(exc).__name__})
+                  "error_class": type(exc).__name__}, scope)
             return
         submitted.append(fut)
         fut.add_done_callback(on_done)

@@ -122,16 +122,22 @@ class _Pending:
     """One in-flight digest: the request and every future joined to it.
 
     ``deadline`` is an absolute ``time.monotonic()`` instant (None for
-    no deadline) — absolute so queue wait counts against it.
+    no deadline) — absolute so queue wait counts against it.  ``scopes``
+    holds ``(obs.Scope, submit ns)`` of each waiter that records its
+    spans (empty for the rest).
     """
 
-    __slots__ = ("digest", "request", "futures", "deadline")
+    __slots__ = ("digest", "request", "futures", "deadline", "scopes")
 
     def __init__(self, digest, request, deadline=None):
         self.digest = digest
         self.request = request
         self.futures: list = []
         self.deadline = deadline
+        self.scopes: list = []
+
+    def recording(self) -> list:
+        return [sc for sc, _ in self.scopes]
 
 
 def _coalesce_key(request: PriceRequest):
@@ -241,14 +247,16 @@ class Scheduler:
 
     # ---- client side ---------------------------------------------------
     def submit(self, request: PriceRequest, digest: str | None = None, *,
-               deadline_s: float | None = None) -> Future:
+               deadline_s: float | None = None, scope=None) -> Future:
         """Queue one request; the future resolves to its ``PriceResult``.
 
         ``deadline_s`` (falling back to ``default_deadline_s``) bounds the
         wall time this request may spend queued + priced; past it, the
         future resolves to a ``degraded=True`` bound ranking.  Raises
         ``QueueFullError`` when the queue is at ``max_queue`` (memo hits
-        and joins are exempt — they need no queue slot).
+        and joins are exempt — they need no queue slot).  ``scope`` (an
+        ``obs.Scope``) records this request's ``serve.queue`` wait and the
+        sweep that serves it.
         """
         digest = digest or request_digest(request)
         if deadline_s is None:
@@ -269,6 +277,8 @@ class Scheduler:
                 self.counters["requests"] += 1
                 self.counters["dedupe_joins"] += 1
                 pending.futures.append(fut)
+                if scope is not None:
+                    pending.scopes.append((scope, time.perf_counter_ns()))
                 return fut
             if self.max_queue is not None and len(self._queue) >= self.max_queue:
                 # rejected before being counted as a request: the counter
@@ -283,6 +293,8 @@ class Scheduler:
                         if deadline_s is not None else None)
             pending = _Pending(digest, request, deadline)
             pending.futures.append(fut)
+            if scope is not None:
+                pending.scopes.append((scope, time.perf_counter_ns()))
             self._inflight[digest] = pending
             self._queue.append(pending)
             self._wake.notify()
@@ -432,6 +444,11 @@ class Scheduler:
                 if not self._queue and self._stop:
                     return
                 batch, self._queue = self._queue, []
+            taken = None
+            for p in batch:
+                for sc, submitted in p.scopes:
+                    taken = taken or time.perf_counter_ns()
+                    sc.stamp("serve.queue", "serve", submitted, taken)
             self._serve_batch(batch)
 
     def _serve_batch(self, batch):
@@ -465,10 +482,17 @@ class Scheduler:
                 self._inflight.pop(pending.digest, None)
                 self.counters["cancelled"] += 1
             return
+        # the sweep's spans go to every waiter that records them; the
+        # result is set outside, once they have them
+        with obs.collect(pending.recording(), len(pending.futures)):
+            outcome = self._price_one(pending)
+        self._resolve(pending, *outcome)
+
+    def _price_one(self, pending) -> tuple:
+        """(result, exception, memoize) for one pending request."""
         deadline = pending.deadline
         if deadline is not None and time.monotonic() >= deadline:
-            self._serve_degraded(pending)
-            return
+            return self._degrade(pending)
         progress = None
         if deadline is not None:
             def progress(done, total):
@@ -481,13 +505,12 @@ class Scheduler:
                 result = price(pending.request, engine=self.engine,
                                progress=progress)
         except DeadlineExceeded:
-            self._serve_degraded(pending)
+            return self._degrade(pending)
         except BaseException as exc:
-            self._resolve(pending, None, exc)
-        else:
-            self._resolve(pending, result, None)
+            return None, exc, True
+        return result, None, True
 
-    def _serve_degraded(self, pending):
+    def _degrade(self, pending) -> tuple:
         """Deadline blown: answer with the closed-form bound ranking,
         explicitly flagged, instead of timing out or going silent."""
         try:
@@ -495,12 +518,11 @@ class Scheduler:
                           digest=pending.digest[:12]):
                 result = price_bounds(pending.request, engine=self.engine)
         except BaseException as exc:
-            self._resolve(pending, None, exc)
-            return
+            return None, exc, True
         with self._lock:
             self.counters["degraded"] += 1
         # not memoized: the next undeadlined ask deserves the exact sweep
-        self._resolve(pending, result, None, memoize=False)
+        return result, None, False
 
     def _serve_coalesced(self, group):
         tmpl = group[0].request
@@ -515,8 +537,10 @@ class Scheduler:
             top_k=tmpl.top_k, strict=tmpl.strict,
             machine_axis=tmpl.machine_axis,
         )
+        scopes = [sc for p in group for sc in p.recording()]
         try:
-            with obs.span("serve.coalesce", "serve", requests=len(group)):
+            with obs.collect(scopes, sum(len(p.futures) for p in group)), \
+                    obs.span("serve.coalesce", "serve", requests=len(group)):
                 merged = price(merged_request, engine=self.engine)
         except BaseException as exc:
             for p in group:
@@ -556,9 +580,10 @@ class Scheduler:
             # the commit point for this digest's warm-restart durability;
             # a failed append only costs warmth, never correctness
             try:
-                self._memo_journal.append(
-                    json.dumps([pending.digest, wire],
-                               separators=(",", ":")).encode())
+                with obs.collect(pending.recording(), len(futures)):
+                    self._memo_journal.append(
+                        json.dumps([pending.digest, wire],
+                                   separators=(",", ":")).encode())
             except OSError:
                 pass
         for fut in futures:

@@ -101,31 +101,42 @@ def test_spans_are_thread_safe_and_threads_nest_independently():
 
 
 def test_adopt_drain_ingest_round_trip():
+    """The worker side of the pool boundary, in-process: a chunk run under
+    the parent's context ships its spans, parented there, and the parent
+    ingests them."""
+    from repro.core.engine.pool import _pool_batch
+
     obs.enable()
     with obs.span("parent") as sp:
         ctx = obs.current_context()
         assert ctx == (sp.trace_id, sp.span_id)
     parent_rec = obs.spans()[0]
 
-    # simulate the worker side of the pool boundary in-process
+    def task(x):
+        with obs.span("task", "task"):
+            return x + 1
+
     shipped = []
 
     def worker():
-        obs.adopt(ctx)
-        with obs.span("pool.chunk", "pool", tasks=3):
-            pass
-        shipped.extend(obs.drain())
+        shipped.append(_pool_batch([(task, (1,)), (task, (2,))], ctx))
 
     t = threading.Thread(target=worker)
     t.start()
     t.join()
+    (tag, outcomes, records), = shipped
+    assert tag == "obs" and outcomes == [("ok", 2), ("ok", 3)]
+    # the worker's spans went with the chunk, not into the buffer
+    assert obs.spans() == [parent_rec]
     # tuples survive pickling as plain sequences; ingest re-wraps them
-    obs.ingest([tuple(r) for r in shipped])
+    obs.ingest([tuple(r) for r in records])
     recs = obs.spans()
-    assert len(recs) == 2
+    assert len(recs) == 4
     child = next(r for r in recs if r.name == "pool.chunk")
     assert child.parent_id == parent_rec.span_id
     assert child.trace_id == parent_rec.trace_id
+    assert child.args["tasks"] == 2
+    assert {r.parent_id for r in recs if r.name == "task"} == {child.span_id}
 
 
 def test_current_context_is_none_while_disabled():
@@ -352,22 +363,6 @@ def test_sweep_spans_cover_pipeline_and_merge_worker_processes():
         assert {r.pid for r in recs} - {os.getpid()}
         tasks = [r for r in recs if r.cat == "task"]
         assert tasks and all(r.pid != os.getpid() for r in tasks)
-
-
-def test_explorer_trace_out_writes_per_sweep(tmp_path):
-    from repro.core.engine import Explorer
-    from repro.core.machines import A100
-    from repro.core.selector import enumerate_gpu_configs
-    from repro.core.specs import star_stencil_3d
-
-    path = tmp_path / "sweep.json"
-    ex = Explorer(trace_out=str(path))
-    assert obs.enabled()                  # ctor opt-in
-    ex._rank_gpu(star_stencil_3d(r=1, domain=(16, 24, 32)), A100,
-                 enumerate_gpu_configs(128), top_k=3)
-    trace = json.loads(path.read_text())
-    assert any(e.get("name") == "engine.sweep"
-               for e in trace["traceEvents"])
 
 
 def test_report_metrics_carry_canonical_names():
