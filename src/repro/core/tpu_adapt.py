@@ -45,8 +45,8 @@ def _roundup(x: int, m: int) -> int:
 # the f32 results of its dots, whichever is larger, plus this much of its
 # own.  Calibrated against compiles for a described v5e at the six
 # generators' real sizes (JAX 0.9.0, libtpu 0.0.34), bisecting the least
-# limit that compiles: stencil ring needs 14.1 MiB beyond its footprint
-# (bound 17.1), stencil replane 11.7 (17.7), flash attention (1024, 2048)
+# limit that compiles: stencil ring needs 10.1 MiB beyond its footprint
+# (bound 21.5), stencil replane 11.7 (17.7), flash attention (1024, 2048)
 # 8.9 (12.5), ytile_ring ty=256 8.1 (16.9), matmul 1024x2048x1024 4.5
 # (16.0), LBM replane 2.5 (11.0); jacobi ty=1024 compiles at no limit and
 # is skipped.  ``python -m repro.kernels.compile_probe`` checks every

@@ -63,7 +63,7 @@ def _candidates(r: int, domain: tuple, elem_bytes: int) -> tuple:
                                 elem_bytes=elem_bytes))
         if variant == "ring":
             return KernelBuild(
-                call, (arg("src", (Zp, Yp, Xp), dtype),),
+                call, (arg("src", domain, dtype),),
                 name=f"star{r}_ring", operand_names=["src", "dst"],
                 costs=CostModel(vpu_elems_per_step=fl * Y * X * Z / Zp,
                                 vpu_shape=(Y, X),

@@ -12,7 +12,10 @@ Warpspeed-TPU estimator selects analytically:
   * ``ytile_ring`` — ring variant with y-tiling for domains whose planes
     violate the VMEM layer condition; trades 2x halo refetch for residency.
 
-All variants keep x/y halos in-plane via static slices of padded planes.
+All variants take every tap as a static slice of a plane with a zero
+border.  ``replane`` and ``ytile_ring`` read that border from a source
+zero-padded in HBM; ``ring`` reads the source as it is and keeps the
+border, and the zero planes before and after it, in its VMEM ring.
 """
 from __future__ import annotations
 
@@ -28,9 +31,10 @@ from repro.kernels import pallas_call
 
 def _apply_star(plane, weights, r, Y, X, y0, x0):
     """Weighted star sum.  ``plane(dz) -> (ref, lead)`` names the ref and
-    leading index holding the padded plane at z-offset dz; every tap is a
-    static (Y, X) window sliced straight off that ref, its origin at
-    (y0, x0) of the padded plane plus the tap's in-plane shift.
+    leading index holding the zero-bordered plane at z-offset dz; every
+    tap is a static (Y, X) window sliced straight off that ref, its origin
+    at (y0, x0) of that plane (where its interior starts) plus the tap's
+    in-plane shift.
     """
 
     def tap(dz, dy, dx):
@@ -80,35 +84,63 @@ def make_replane(r: int, domain: tuple, weights, dtype=jnp.float32):
 
 
 def make_ring(r: int, domain: tuple, weights, dtype=jnp.float32):
-    """Variant B: leading-plane ref + (2r+1)-plane VMEM ring buffer."""
+    """Variant B: leading-plane ref + (2r+1)-plane VMEM ring buffer.
+
+    Takes the unpadded source ``(Z, Y, X)`` and supplies the zero halo in
+    VMEM.  Each ring slot holds one plane inside a zero border, its
+    interior at ``(oy, ox)`` rounded up from r to the (8, 128) tile, so
+    the plane's store and the centre and z-taps are aligned, the y-taps
+    shift on sublanes only and the x-taps on lanes only.  Step t holds
+    padded plane t: source plane t - r, or zeros outside the source.  The
+    source block index is clamped, and a repeated block is not fetched
+    again, so the source is read once.
+    """
     Z, Y, X = domain
-    Yp, Xp = Y + 2 * r, X + 2 * r
+    oy, ox = -(-r // 8) * 8, -(-r // 128) * 128
     Zp = Z + 2 * r
     nring = 2 * r + 1
     weights = tuple(float(w) for w in weights)
 
     def kernel(s_ref, o_ref, ring):
         t = pl.program_id(0)
-        ring[t % nring] = s_ref[0]
+        slot = t % nring
+
+        @pl.when(t == 0)
+        def _():
+            ring[...] = jnp.zeros(ring.shape, dtype)  # the zero borders
+
+        # planes before the source (t < r) keep the zeros written above
+        @pl.when(t >= r)
+        def _():
+            @pl.when(t < Z + r)
+            def _():
+                ring[slot, oy:oy + Y, ox:ox + X] = s_ref[0]
+
+        @pl.when(t >= Z + r)
+        def _():
+            ring[slot, oy:oy + Y, ox:ox + X] = jnp.zeros((Y, X), dtype)
 
         @pl.when(t >= 2 * r)
         def _():
             # center plane is t - r (padded z coords); slot modulo ring
             o_ref[0] = _apply_star(lambda dz: (ring, (t - r + dz) % nring),
-                                   weights, r, Y, X, r, r)
+                                   weights, r, Y, X, oy, ox)
 
-    def call(src_padded):
+    def call(src):
         return pallas_call(
             kernel,
             name="stencil3d25_ring",
             grid=(Zp,),
-            in_specs=[pl.BlockSpec((1, Yp, Xp), lambda t: (t, 0, 0))],
+            in_specs=[pl.BlockSpec(
+                (1, Y, X),
+                lambda t: (jnp.minimum(jnp.maximum(t - r, 0), Z - 1), 0, 0))],
             out_specs=pl.BlockSpec(
                 (1, Y, X), lambda t: (jnp.maximum(t - 2 * r, 0), 0, 0)
             ),
             out_shape=jax.ShapeDtypeStruct((Z, Y, X), dtype),
-            scratch_shapes=[pltpu.VMEM((nring, Yp, Xp), dtype)],
-        )(src_padded)
+            scratch_shapes=[
+                pltpu.VMEM((nring, Y + 2 * oy, X + 2 * ox), dtype)],
+        )(src)
 
     return call
 

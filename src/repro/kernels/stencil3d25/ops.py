@@ -13,15 +13,21 @@ from .ref import pad_input, star_weights
 
 @functools.partial(jax.jit, static_argnames=("r", "variant", "ty", "weights"))
 def _apply(src, *, weights: tuple, r: int, variant: str, ty):
-    """weights are codegen constants (baked into the kernel), hence static."""
+    """weights are codegen constants (baked into the kernel), hence static.
+
+    ``ring`` takes the source as it is and keeps its zero halo in VMEM;
+    ``replane`` and ``ytile_ring`` take it zero-padded by r on every side
+    in HBM, ``ytile_ring`` further down in y to whole tiles plus one."""
     Z, Y, X = src.shape
-    padded = jnp.pad(src, ((r, r), (r, r), (r, r)))
+    kern = make_kernel(variant, r, (Z, Y, X), weights, src.dtype, ty)
+    if variant == "ring":
+        return kern(src)
+    padded = pad_input(src, r)
     if variant == "ytile_ring":
         t = ty or max(2 * r, 8)
         ny = Y // t
         extra = (ny + 1) * t - (Y + 2 * r)
         padded = jnp.pad(padded, ((0, 0), (0, extra), (0, 0)))
-    kern = make_kernel(variant, r, (Z, Y, X), weights, src.dtype, ty)
     return kern(padded)
 
 

@@ -108,19 +108,21 @@ def _hand_stencil_replane(r, domain, elem_bytes):
 
 
 def _hand_stencil_ring(r, domain, elem_bytes):
+    """The unpadded source plane by plane; the ring's planes carry their
+    zero border, the interior at r rounded up to the (8, 128) tile."""
     Z, Y, X = domain
-    Yp, Xp = Y + 2 * r, X + 2 * r
+    oy, ox = -(-r // 8) * 8, -(-r // 128) * 128
     Zp = Z + 2 * r
     fl = float(6 * r + 1) * 2.0
     return PallasKernelSpec(
         name=f"star{r}_ring", grid=(Zp,),
         operands=(
-            OperandSpec("src", (1, Yp, Xp), elem_bytes, grid_deps=(0,)),
+            OperandSpec("src", (1, Y, X), elem_bytes, grid_deps=(0,)),
             OperandSpec("dst", (1, Y, X), elem_bytes, grid_deps=(0,),
                         is_output=True),
         ),
         vpu_elems_per_step=fl * Y * X * Z / Zp, vpu_shape=(Y, X),
-        scratch_bytes=(2 * r + 1) * Yp * Xp * elem_bytes,
+        scratch_bytes=(2 * r + 1) * (Y + 2 * oy) * (X + 2 * ox) * elem_bytes,
         work_per_step=float(Y * X) * Z / Zp, elem_bytes=elem_bytes)
 
 

@@ -112,7 +112,7 @@ def test_reject_scratch_staged_gpu_lowering():
 
     traced = trace_kernel(
         make_ring(1, (8, 16, 32), (1.0,) * 7, jnp.float32),
-        [arg("src", (10, 18, 34))], name="ring", trace_body=True)
+        [arg("src", (8, 16, 32))], name="ring", trace_body=True)
     assert traced.body.ok
     with pytest.raises(TraceError, match="scratch"):
         lower_gpu(traced)
@@ -137,7 +137,7 @@ def test_price_kernel_reports_gpu_rejection():
 
     report = price_kernel(
         make_ring(1, (8, 16, 32), (1.0,) * 7, jnp.float32),
-        [arg("src", (10, 18, 34))],
+        [arg("src", (8, 16, 32))],
         machines=[V100, TPU_V5E], name="ring")
     assert report.best("ring", TPU_V5E.name) is not None
     skips = report.skipped_for("ring", V100.name)

@@ -14,23 +14,42 @@ from repro.kernels.stencil3d25.ref import pad_input, star_stencil_ref, star_weig
 
 
 @pytest.mark.parametrize("r", [1, 2, 4])
-@pytest.mark.parametrize("variant,ty", [("replane", None), ("ring", None), ("ytile_ring", 8)])
+@pytest.mark.parametrize("variant,ty,dom", [
+    pytest.param("replane", None, (5, 16, 24), id="replane-None"),
+    pytest.param("ring", None, (5, 16, 24), id="ring-None"),
+    pytest.param("ring", None, (12, 16, 128), id="ring-None-12x16x128"),
+    pytest.param("ytile_ring", 8, (5, 16, 24), id="ytile_ring-8")])
 @pytest.mark.parametrize("dtype", [jnp.float32])
-def test_stencil_variants(r, variant, ty, dtype):
-    Z, Y, X = 5, 16, 24
+def test_stencil_variants(r, variant, ty, dom, dtype):
+    """ring takes the source unpadded (Z = 5 < 2r covers its clamped
+    source block and zero planes); the others take it zero-padded."""
+    Z, Y, X = dom
     src = jax.random.normal(jax.random.PRNGKey(r), (Z, Y, X), dtype=dtype)
     w = star_weights(r, dtype)
     ref = star_stencil_ref(pad_input(src, r), w, r)
-    padded = pad_input(src, r)
+    arg = src if variant == "ring" else pad_input(src, r)
     if variant == "ytile_ring":
         if ty < 2 * r:
             pytest.skip("ty < 2r")
         ny = Y // ty
         extra = (ny + 1) * ty - (Y + 2 * r)
-        padded = jnp.pad(padded, ((0, 0), (0, extra), (0, 0)))
+        arg = jnp.pad(arg, ((0, 0), (0, extra), (0, 0)))
     k = make_stencil(variant, r, (Z, Y, X), tuple(float(x) for x in w), dtype, ty)
-    out = k(padded)
+    out = k(arg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("config,pads", [
+    ({"variant": "ring"}, False), ({"variant": "ytile_ring", "ty": 8}, True)])
+def test_stencil_pads_in_hbm_only_for_padded_variants(config, pads):
+    """ring keeps its zero halo in VMEM: its program has no pad op."""
+    from repro.kernels.stencil3d25.ops import star_stencil
+
+    src = jax.ShapeDtypeStruct((12, 16, 128), jnp.float32)
+    w = (1.0 / 25,) * 25
+    hlo = jax.jit(lambda s: star_stencil(s, w, r=4, config=config)) \
+        .lower(src).as_text()
+    assert ("stablehlo.pad" in hlo) == pads
 
 
 @pytest.mark.parametrize("dom", [(3, 8, 16), (4, 16, 8)])

@@ -130,6 +130,20 @@ def test_stencil_selector_prefers_ring_until_lc_breaks():
     assert all(rc.config["variant"] != "ring" for rc in big)
 
 
+def test_stencil_selector_keeps_ring_at_the_sweep_domain():
+    """The benchmark's domain: ring, with its zero-bordered planes at the
+    tile-aligned origin (8, 128), stays first and fits VMEM."""
+    from repro.kernels.stencil3d25.generator import rank_configs
+
+    best = rank_configs(4, (512, 512, 640), elem_bytes=4)[0]
+    assert best.config == {"variant": "ring"}
+    assert best.spec.scratch_bytes == 9 * (512 + 16) * (640 + 256) * 4
+    est = best.estimate
+    assert est.feasible
+    assert est.vmem_alloc_bytes + est.detail["vmem_reserve"] \
+        <= TPU_V5E.vmem_bytes
+
+
 def test_matmul_selector_prefers_bigger_blocks():
     from repro.kernels.matmul.generator import rank_configs
 
