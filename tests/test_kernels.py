@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels.flash_attention.kernel import make_flash_attention, make_flash_decode
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.lbm_d3q15.generator import _space as lbm_space
 from repro.kernels.lbm_d3q15.kernel import make_kernel as make_lbm
 from repro.kernels.lbm_d3q15.ref import WEIGHTS, lbm_step_ref, pad_inputs
 from repro.kernels.matmul.kernel import make_matmul
@@ -67,6 +68,33 @@ def test_lbm_variants(dom, variant, ty):
         ph_p = jnp.pad(ph_p, ((0, 0), (0, extra), (0, 0)))
     out = make_lbm(variant, dom, ty)(pdf_p, ph_p)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+LBM_DOMAIN = (8, 32, 128)    # Mosaic-tileable planes; Y = 32 keeps two ytiles
+
+
+@pytest.mark.parametrize("config", list(lbm_space(LBM_DOMAIN)),
+                         ids=lambda c: c["variant"] + str(c.get("ty", "")))
+def test_lbm_step_matches_the_reference(config):
+    """The public ``lbm_step`` for each candidate, its own padding (ytile's
+    extra y rows too) included, on PDFs off equilibrium (w_q * phase plus
+    noise), against ``lbm_step_ref`` for both returned values."""
+    from repro.kernels.lbm_d3q15.ops import lbm_step
+
+    kp, kn = jax.random.split(jax.random.PRNGKey(5))
+    phase = jax.random.uniform(kp, LBM_DOMAIN)
+    noise = 1e-3 * jax.random.normal(kn, (15, *LBM_DOMAIN))
+    pdf = jnp.asarray(WEIGHTS)[:, None, None, None] * phase + noise
+    want_pdf, want_phase = lbm_step_ref(*pad_inputs(pdf, phase))
+    got_pdf, got_phase = lbm_step(pdf, phase, config=config)
+    # Both sides evaluate the same float32 expressions, save the normal's
+    # rsqrt against ** -0.5 and the order of the phase sum: a few ulp of
+    # outputs below 1 (ulp 6e-8), far under a PDF pulled from a wrong cell
+    # (the noise alone differs by ~1e-3 between neighbours).
+    np.testing.assert_allclose(np.asarray(got_pdf), np.asarray(want_pdf),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_phase), np.asarray(want_phase),
+                               rtol=0, atol=1e-6)
 
 
 @pytest.mark.parametrize("shape", [(128, 128, 128), (256, 384, 128), (128, 256, 256)])
