@@ -48,7 +48,7 @@ def _roundup(x: int, m: int) -> int:
 # limit that compiles: stencil ring needs 10.1 MiB beyond its footprint
 # (bound 21.5), stencil replane 11.7 (17.7), flash attention (1024, 2048)
 # 8.9 (12.5), ytile_ring ty=256 8.1 (16.9), matmul 1024x2048x1024 4.5
-# (16.0), LBM replane 2.5 (11.0); jacobi ty=1024 compiles at no limit and
+# (16.0), LBM replane 3.9 (17.5); jacobi ty=1024 compiles at no limit and
 # is skipped.  ``python -m repro.kernels.compile_probe`` checks every
 # candidate against the compiler.
 VMEM_BASE_RESERVE_BYTES = 4 * 1024 * 1024

@@ -39,7 +39,7 @@ def _candidates(domain: tuple, elem_bytes: int) -> tuple:
     from .kernel import make_kernel
 
     Z, Y, X = domain
-    Yp, Xp = Y + 2, X + 2
+    Xp = X + 2
     dtype = dtype_for(elem_bytes)
 
     def build(cfg):
@@ -48,8 +48,7 @@ def _candidates(domain: tuple, elem_bytes: int) -> tuple:
         if variant == "replane":
             return KernelBuild(
                 call,
-                (arg("pdf", (15, Z + 2, Yp, Xp), dtype),
-                 arg("phase", (Z + 2, Yp, Xp), dtype)),
+                (arg("pdf", (15, Z, Y, X), dtype), arg("phase", domain, dtype)),
                 name="lbm_replane",
                 operand_names=[f"pdf{q}" for q in range(15)]
                 + [f"phase{k}" for k in range(3)] + ["dst"],

@@ -1,16 +1,18 @@
 """Pallas TPU kernels for the D3Q15 Allen-Cahn interface-tracking LBM.
 
 The z-streaming of the pull scheme is expressed *entirely in the BlockSpec
-index maps*: PDF q's input ref maps grid step t to padded plane t+1-cz(q),
-so every PDF plane is fetched exactly once (revisit analysis gives fetch
-multiplicity 1 per plane) — the TPU equivalent of the GPU's streaming-store
-friendliness the paper measures.  x/y shifts stay in-plane via static slices
-of the halo-padded planes.
+index maps*: PDF q's input ref maps grid step t to plane t-cz(q) of the
+source, so every PDF plane is fetched exactly once (revisit analysis gives
+fetch multiplicity 1 per plane) — the TPU equivalent of the GPU's
+streaming-store friendliness the paper measures.  x/y shifts stay in-plane
+as static slices of planes inside a zero border.
 
 Variants:
-  * ``replane`` — 15 PDF plane refs + 3 phase plane refs; no scratch.
+  * ``replane`` — 15 PDF plane refs + 3 phase plane refs on the unpadded
+    source; the zero halo, in z and in-plane, is made in a VMEM scratch.
   * ``ytile``   — all fields y-tiled (2 refs each for the tile+halo trick)
-    for domains whose planes violate the VMEM capacity (layer) condition.
+    for domains whose planes violate the VMEM capacity (layer) condition;
+    reads a source zero-padded in HBM.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import pallas_call
 
@@ -28,7 +31,7 @@ from .ref import VELOCITIES, WEIGHTS
 def _compute(pdf_tap, phase_tap, o_ref, tau, kappa):
     """Shared collide+stream math, stored PDF by PDF into ``o_ref[q, 0]``.
 
-    ``pdf_tap(q, dy, dx)``: the output-sized window of PDF q's padded
+    ``pdf_tap(q, dy, dx)``: the output-sized window of PDF q's zero-bordered
     plane (already at the right z, pull scheme) shifted by (dy, dx).
     ``phase_tap(k, dy, dx)``: the same window of the phase plane at z-1+k.
     """
@@ -47,35 +50,76 @@ def _compute(pdf_tap, phase_tap, o_ref, tau, kappa):
 
 
 def make_replane(domain: tuple, tau: float = 0.8, kappa: float = 0.15, dtype=jnp.float32):
+    """15 PDF plane refs + 3 phase plane refs on the unpadded source.
+
+    PDF q's ref maps grid step t to source plane t - cz(q), phase k's to
+    plane t - 1 + k, each clamped into [0, Z): a repeated block index is
+    not fetched again, so each operand still reads every plane once.  The
+    zero halo is made in VMEM: each plane but the rest PDF's is copied
+    into a scratch slot whose interior sits at the (8, 128)-aligned
+    (oy, ox) inside a zero border, and the first and last steps zero the
+    slots whose unclamped plane lies outside the source.  Every tap is a
+    static (Y, X) window of a slot, so the y-taps shift on sublanes only
+    and the x-taps on lanes only.
+    """
     Z, Y, X = domain
-    Yp, Xp = Y + 2, X + 2
+    oy, ox = 8, 128
+    # slot s holds PDF s + 1 (s < 14) or the phase at z - 1 + (s - 14);
+    # its plane at step t is t + dz[s]
+    dz = [-cz for _, _, cz in VELOCITIES[1:]] + [-1, 0, 1]
 
     def kernel(*refs):
         pdf_refs = refs[:15]
         phases = refs[15:18]
-        o_ref = refs[18]
+        o_ref, pad = refs[18], refs[19]
+        t = pl.program_id(0)
+
+        @pl.when(t == 0)
+        def _():
+            pad[...] = jnp.zeros(pad.shape, dtype)  # the zero borders
+
+        def interior(s):
+            return (s, slice(oy, oy + Y), slice(ox, ox + X))
+
+        for q in range(1, 15):
+            pad[interior(q - 1)] = pdf_refs[q][0, 0]
+        for k in range(3):
+            pad[interior(14 + k)] = phases[k][0]
+
+        # planes -1 and Z of the source are zeros
+        for step, edge in ((0, -1), (Z - 1, 1)):
+            @pl.when(t == step)
+            def _(edge=edge):
+                for s in range(17):
+                    if dz[s] == edge:
+                        pad[interior(s)] = jnp.zeros((Y, X), dtype)
+
+        def window(s, dy, dx):
+            return pad[s, oy + dy:oy + dy + Y, ox + dx:ox + dx + X]
 
         def pdf_tap(q, dy, dx):
-            return pdf_refs[q][0, 0, 1 + dy:1 + dy + Y, 1 + dx:1 + dx + X]
+            return pdf_refs[0][0, 0] if q == 0 else window(q - 1, dy, dx)
 
-        def phase_tap(k, dy, dx):
-            return phases[k][0, 1 + dy:1 + dy + Y, 1 + dx:1 + dx + X]
+        _compute(pdf_tap, lambda k, dy, dx: window(14 + k, dy, dx),
+                 o_ref, tau, kappa)
 
-        _compute(pdf_tap, phase_tap, o_ref, tau, kappa)
+    def clamped(z):
+        return jnp.minimum(jnp.maximum(z, 0), Z - 1)
 
-    def call(pdf_padded, phase_padded):
-        """pdf_padded (15, Z+2, Yp, Xp), phase_padded (Z+2, Yp, Xp)."""
+    def call(pdf, phase):
+        """pdf (15, Z, Y, X), phase (Z, Y, X)."""
         in_specs = []
         for q, (cx, cy, cz) in enumerate(VELOCITIES):
             in_specs.append(
                 pl.BlockSpec(
-                    (1, 1, Yp, Xp),
-                    functools.partial(lambda q, cz, t: (q, t + 1 - cz, 0, 0), q, cz),
+                    (1, 1, Y, X),
+                    functools.partial(lambda q, cz, t: (q, clamped(t - cz), 0, 0), q, cz),
                 )
             )
         for k in range(3):
             in_specs.append(
-                pl.BlockSpec((1, Yp, Xp), functools.partial(lambda k, t: (t + k, 0, 0), k))
+                pl.BlockSpec((1, Y, X),
+                             functools.partial(lambda k, t: (clamped(t - 1 + k), 0, 0), k))
             )
         return pallas_call(
             kernel,
@@ -84,7 +128,8 @@ def make_replane(domain: tuple, tau: float = 0.8, kappa: float = 0.15, dtype=jnp
             in_specs=in_specs,
             out_specs=pl.BlockSpec((15, 1, Y, X), lambda t: (0, t, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((15, Z, Y, X), dtype),
-        )(*([pdf_padded] * 15 + [phase_padded] * 3))
+            scratch_shapes=[pltpu.VMEM((17, Y + 2 * oy, X + 2 * ox), dtype)],
+        )(*([pdf] * 15 + [phase] * 3))
 
     return call
 

@@ -160,14 +160,16 @@ def test_lbm_traced_matches_handwritten():
 
     domain, eb = (8, 16, 32), 4
     Z, Y, X = domain
-    Yp, Xp = Y + 2, X + 2
+    Xp = X + 2
     traced = {tuple(sorted(c.items())): s
               for c, s in candidate_specs(domain, eb)}
+    # replane reads the unpadded planes and keeps 17 zero-bordered copies
+    # of them, interior at the (8, 128) tile, in VMEM
     ops = tuple(
-        OperandSpec(f"pdf{q}", (1, 1, Yp, Xp), eb, grid_deps=(0,))
+        OperandSpec(f"pdf{q}", (1, 1, Y, X), eb, grid_deps=(0,))
         for q in range(15)
     ) + tuple(
-        OperandSpec(f"phase{k}", (1, Yp, Xp), eb, grid_deps=(0,))
+        OperandSpec(f"phase{k}", (1, Y, X), eb, grid_deps=(0,))
         for k in range(3)
     ) + (
         OperandSpec("dst", (15, 1, Y, X), eb, grid_deps=(0,), is_output=True),
@@ -175,6 +177,7 @@ def test_lbm_traced_matches_handwritten():
     hand = PallasKernelSpec(
         name="lbm_replane", grid=(Z,), operands=ops,
         vpu_elems_per_step=float(FLOPS_PER_LUP * Y * X), vpu_shape=(Y, X),
+        scratch_bytes=17 * (Y + 16) * (X + 256) * eb,
         work_per_step=float(Y * X), elem_bytes=eb)
     assert_bitwise(traced[(("variant", "replane"),)], hand)
     ty = 8

@@ -53,20 +53,43 @@ def test_stencil_pads_in_hbm_only_for_padded_variants(config, pads):
     assert ("stablehlo.pad" in hlo) == pads
 
 
-@pytest.mark.parametrize("dom", [(3, 8, 16), (4, 16, 8)])
+@pytest.mark.parametrize("config,pads", [
+    ({"variant": "replane"}, False), ({"variant": "ytile", "ty": 8}, True)])
+def test_lbm_pads_in_hbm_only_for_ytile(config, pads):
+    """replane makes its zero halo in VMEM: its program has no pad op."""
+    from repro.kernels.lbm_d3q15.ops import lbm_step
+
+    dom = (8, 32, 128)
+    pdf = jax.ShapeDtypeStruct((15, *dom), jnp.float32)
+    phase = jax.ShapeDtypeStruct(dom, jnp.float32)
+    hlo = jax.jit(lambda p, f: lbm_step(p, f, config=config)) \
+        .lower(pdf, phase).as_text()
+    assert ("stablehlo.pad" in hlo) == pads
+
+
+# Z = 1 puts every cz = +-1 pull in the z-halo; (3, 16, 256) has Y != X
+@pytest.mark.parametrize("dom", [(3, 8, 16), (4, 16, 8), (1, 8, 16), (3, 16, 256)])
 @pytest.mark.parametrize("variant,ty", [("replane", None), ("ytile", 4)])
 def test_lbm_variants(dom, variant, ty):
+    """replane takes the source unpadded; ytile takes it zero-padded, y
+    further down to whole tiles plus one.  The PDFs are off equilibrium
+    and non-zero on every face, so a halo read as anything but zeros, or
+    a PDF pulled from a wrong cell, shows."""
     Z, Y, X = dom
-    phase = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(0), dom))
-    pdf = jnp.stack([w * phase for w in WEIGHTS])
+    kp, kn = jax.random.split(jax.random.PRNGKey(0))
+    phase = jax.nn.sigmoid(jax.random.normal(kp, dom))
+    noise = 1e-3 * jax.random.normal(kn, (15, *dom))
+    pdf = jnp.stack([w * phase for w in WEIGHTS]) + noise
     pdf_p, ph_p = pad_inputs(pdf, phase)
     ref, _ = lbm_step_ref(pdf_p, ph_p)
-    if variant == "ytile":
+    if variant == "replane":
+        out = make_lbm(variant, dom)(pdf, phase)
+    else:
         ny = Y // ty
         extra = (ny + 1) * ty - (Y + 2)
         pdf_p = jnp.pad(pdf_p, ((0, 0), (0, 0), (0, extra), (0, 0)))
         ph_p = jnp.pad(ph_p, ((0, 0), (0, extra), (0, 0)))
-    out = make_lbm(variant, dom, ty)(pdf_p, ph_p)
+        out = make_lbm(variant, dom, ty)(pdf_p, ph_p)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
