@@ -37,11 +37,12 @@ a mathematical lower bound on the model's predicted time; a relative safety
 margin of 1e-9 absorbs float-rounding differences between the closed forms
 and the model's own arithmetic, so branch-and-bound pruning is exact.
 
-The Pallas backend wraps ``estimate_pallas`` (already cheap closed-form
-math): one task per (kernel spec, machine), with VMEM feasibility turned
-into a recorded skip reason.  Its bound is the HBM-traffic time floor from
-BlockSpec byte counts (``tpu_adapt.pallas_time_floor``), which shares the
-estimate's float ops and is therefore sound without any margin.
+The Pallas backend has the batched path only: one geometry-keyed
+structure task per kernel spec, one rate-stage call per group of machines
+(``tpu_adapt.pallas_rate_matrix``), entries read from its rows, and VMEM
+infeasibility turned into a recorded skip reason.  It has no tiers: its
+only bound, for degraded pricing, is the rate stage's own ``hbm_time +
+overhead``.
 """
 from __future__ import annotations
 
@@ -63,7 +64,7 @@ from ..perfmodel import (
     l1_rates,
 )
 from ..tpu_adapt import vmem_violation
-from .protocol import EvalResult, RejectedSpec, SkipConfig, Task
+from .protocol import EvalResult, RejectedSpec, Task
 
 # Relative slack applied to the GPU closed-form bounds: the model computes
 # times as 1/(bw / volume) while the bounds compute volume/bw directly, which
@@ -264,8 +265,10 @@ class GPUBackend:
 
         Returns per-machine index orders into ``items`` (best first, ties
         toward earlier enumeration — matching the scalar ``(-perf, index)``
-        sort) plus per-machine ``(item_pos, reason)`` skip lists (empty:
-        the GPU combine has no feasibility constraint)."""
+        sort), per-machine ``(item_pos, reason)`` skip lists (empty: the
+        GPU combine has no feasibility constraint), and ``entry(pos, m)``:
+        the scalar ``combine`` of a selected entry, so returned estimates
+        are bitwise identical to per-machine pricing by construction."""
         import numpy as np
 
         rep = machines[0]
@@ -286,29 +289,14 @@ class GPUBackend:
         idx = np.arange(len(items))
         orders = [np.lexsort((idx, -perf[:, m]))
                   for m in range(len(machines))]
-        return orders, [[] for _ in machines]
 
-    def machine_axis_combine(self, launch: LaunchConfig, machine: GPUMachine,
-                             values: dict) -> tuple:
-        """Scalar entry construction for the selected top-k — the exact
-        ``combine`` arithmetic, so returned estimates are bitwise identical
-        to per-machine pricing by construction."""
-        return self.combine(launch, machine, values)
+        def entry(pos, m):
+            return self.combine(items[pos], machines[m], values_per_item[pos])
+
+        return orders, [[] for _ in machines], entry
 
 
 # --------------------------------------------------------------------------
-def pallas_task(spec, machine: TPUMachine):
-    from ..tpu_adapt import estimate_pallas
-
-    return estimate_pallas(spec, machine)
-
-
-def pallas_bound_task(spec, machine: TPUMachine) -> float:
-    from ..tpu_adapt import pallas_time_floor
-
-    return pallas_time_floor(spec, machine)
-
-
 def pallas_structure_task(spec, geometry: TPUGeometry) -> dict:
     from ..tpu_adapt import pallas_structure
 
@@ -316,55 +304,19 @@ def pallas_structure_task(spec, geometry: TPUGeometry) -> dict:
 
 
 class PallasBackend:
-    """Estimator-protocol backend over the TPU/Pallas analytical model."""
+    """Estimator backend over the TPU/Pallas analytical model.
+
+    Every Pallas cell is priced batched (DESIGN.md §5, §11): one
+    geometry-keyed structure task per candidate, one call of the rate
+    stage (``tpu_adapt.pallas_rate_matrix``) per group of machines that
+    share a geometry, and entries read from its rows.  Items are
+    ``(config_dict, PallasKernelSpec)`` candidates; a ``RejectedSpec``
+    (frontend tracing diagnostics) needs no structure and becomes a
+    recorded skip, as a VMEM-infeasible row does.
+    """
 
     name = "pallas"
 
-    # items are (config_dict, PallasKernelSpec) candidates; a RejectedSpec
-    # spec (frontend tracing diagnostics) needs no structural work — it
-    # resolves straight to a recorded skip in combine
-    def structural_tasks(self, item, machine: TPUMachine) -> list:
-        _, spec = item
-        if isinstance(spec, RejectedSpec):
-            return []
-        return [Task(("pallas", spec, machine), pallas_task, (spec, machine))]
-
-    # ---- tiered bound-then-refine (optional protocol methods) ----------
-    def bound_tasks(self, item, machine: TPUMachine) -> list:
-        _, spec = item
-        if isinstance(spec, RejectedSpec):
-            return []
-        return [Task(("pallas-bound", spec, machine), pallas_bound_task,
-                     (spec, machine))]
-
-    def tiers(self, item, machine: TPUMachine) -> list:
-        return [self.structural_tasks(item, machine)]
-
-    def tier_bound(self, item, machine: TPUMachine, values: dict) -> float:
-        _, spec = item
-        bound = values.get(("pallas-bound", spec, machine))
-        # shares the estimate's float ops exactly (monotone max/+) — no
-        # rounding margin needed
-        return bound if bound is not None else float("-inf")
-
-    def primary_time(self, result: EvalResult) -> float:
-        return result.estimate.total_time
-
-    def combine(self, item, machine: TPUMachine, values: dict) -> tuple:
-        config, spec = item
-        if isinstance(spec, RejectedSpec):
-            raise SkipConfig(spec.reason)
-        est = values[("pallas", spec, machine)]
-        if not est.feasible:
-            raise SkipConfig(vmem_violation(
-                est.vmem_alloc_bytes, est.detail["vmem_reserve"], machine))
-        return config, est, est.work_rate, est.limiter
-
-    def sort_key(self, result: EvalResult) -> tuple:
-        # predicted time ascending; ties toward smaller VMEM footprints
-        return (result.estimate.total_time, result.estimate.vmem_alloc_bytes)
-
-    # ---- machine-axis batched evaluation (DESIGN.md §11) ----------------
     def geometry_key(self, machine: TPUMachine) -> TPUGeometry:
         return machine.geometry
 
@@ -376,58 +328,68 @@ class PallasBackend:
         return [Task(("pallas-struct", spec, geom), pallas_structure_task,
                      (spec, geom))]
 
-    def batch_order(self, items, values_per_item, machines):
-        """Rank every candidate on every machine from the shared structural
-        stage: one ``(candidates x machines)`` rate program, per-machine
-        orders matching the scalar ``(total_time, vmem_alloc, index)`` sort,
-        and VMEM-infeasible / tracer-rejected candidates as per-machine
-        ``(item_pos, reason)`` skips with the scalar path's exact wording."""
-        import numpy as np
-
+    @staticmethod
+    def _rows(items, values_per_item, machines):
+        """Positions of the traced items, their structures, and per machine
+        their rate-stage rows (``tpu_adapt.pallas_rate_matrix``) and the
+        ``(item_pos, reason)`` skips in item order: tracer rejections and
+        VMEM-infeasible rows."""
         from ..tpu_adapt import pallas_rate_matrix
 
         geom = machines[0].geometry
-        live_pos, structs = [], []
-        rejected = []  # (pos, reason)
-        for pos, (item, values) in enumerate(zip(items, values_per_item)):
-            _, spec = item
+        live, structs, rejected = [], [], {}
+        for pos, ((_, spec), values) in enumerate(zip(items,
+                                                      values_per_item)):
             if isinstance(spec, RejectedSpec):
-                rejected.append((pos, f"SkipConfig: {spec.reason}"))
-                continue
-            live_pos.append(pos)
-            structs.append(values[("pallas-struct", spec, geom)])
-        if not structs:
-            return ([np.array([], dtype=int) for _ in machines],
-                    [list(rejected) for _ in machines])
-        total, _, feasible = pallas_rate_matrix(structs, machines)
-        vmem_alloc = np.array([s["vmem_alloc"] for s in structs],
-                              dtype=float)
-        idx = np.arange(len(structs))
-        pos_arr = np.array(live_pos)
-        orders, skips = [], []
-        for m, machine in enumerate(machines):
-            order = np.lexsort((idx, vmem_alloc, total[:, m]))
-            orders.append(pos_arr[order[feasible[order, m]]])
-            mskips = list(rejected)
-            for i in np.flatnonzero(~feasible[:, m]):
-                reason = vmem_violation(structs[i]["vmem_alloc"],
-                                        structs[i]["vmem_reserve"], machine)
-                mskips.append((live_pos[i], f"SkipConfig: {reason}"))
-            skips.append(mskips)
-        return orders, skips
+                rejected[pos] = f"SkipConfig: {spec.reason}"
+            else:
+                live.append(pos)
+                structs.append(values[("pallas-struct", spec, geom)])
+        rows = pallas_rate_matrix(structs, machines)
+        skips = []
+        for machine, mrows in zip(machines, rows):
+            mskips = dict(rejected)
+            for pos, s, row in zip(live, structs, mrows):
+                if not row.feasible:
+                    mskips[pos] = "SkipConfig: " + vmem_violation(
+                        s["vmem_alloc"], s["vmem_reserve"], machine)
+            skips.append(sorted(mskips.items()))
+        return live, structs, rows, skips
 
-    def machine_axis_combine(self, item, machine: TPUMachine,
-                             values: dict) -> tuple:
-        """Scalar estimate for the selected top-k entries — the same
-        ``estimate_pallas`` every path runs, so results are bitwise
-        identical to per-machine pricing by construction."""
-        from ..tpu_adapt import estimate_pallas
+    def batch_order(self, items, values_per_item, machines):
+        """Rank every candidate on every machine from one rate-stage call.
 
-        config, spec = item
-        if isinstance(spec, RejectedSpec):
-            raise SkipConfig(spec.reason)
-        est = estimate_pallas(spec, machine)
-        if not est.feasible:
-            raise SkipConfig(vmem_violation(
-                est.vmem_alloc_bytes, est.detail["vmem_reserve"], machine))
-        return config, est, est.work_rate, est.limiter
+        Returns per-machine orders of item positions (by ``(total_time,
+        vmem_alloc, index)``, feasible rows only), per-machine
+        ``(item_pos, reason)`` skips in item order, and ``entry(pos, m)``:
+        ``(config, estimate, perf, limiter)`` read from the rate stage's
+        row for item ``pos`` on machine ``m``."""
+        from ..tpu_adapt import pallas_estimate
+
+        live, structs, rows, skips = self._rows(items, values_per_item,
+                                                machines)
+        orders = [
+            [live[i] for _, _, i in sorted(
+                (row.total, s["vmem_alloc"], i)
+                for i, (s, row) in enumerate(zip(structs, mrows))
+                if row.feasible)]
+            for mrows in rows]
+        index = {pos: i for i, pos in enumerate(live)}
+
+        def entry(pos, m):
+            config, spec = items[pos]
+            i = index[pos]
+            est = pallas_estimate(spec.name, structs[i], rows[m][i])
+            return config, est, est.work_rate, est.limiter
+
+        return orders, skips, entry
+
+    def batch_bounds(self, items, values_per_item, machine: TPUMachine):
+        """Degraded pricing (DESIGN.md §13): each feasible item's HBM-volume
+        floor ``hbm_time + overhead``, read from the rate stage (a lower
+        bound on its ``total``), as ``(item_pos, bound)`` pairs, plus the
+        ``(item_pos, reason)`` skips."""
+        live, _, [rows], [skips] = self._rows(items, values_per_item,
+                                              [machine])
+        return ([(pos, row.hbm_time + row.overhead)
+                 for pos, row in zip(live, rows) if row.feasible], skips)

@@ -5,7 +5,7 @@ API.  Pricing a configuration space runs in five stages:
 
   1. **enumerate** — collect the candidate configurations per (workload,
      machine) cell and ask the backend for their structural tasks;
-  2. **prune** (only with ``top_k`` and a bound-capable backend) — evaluate
+  2. **prune** (only with ``top_k`` and a GPU backend) — evaluate
      each configuration's closed-form lower bound on predicted time (cheap:
      no grid walk, no wave model), then branch-and-bound: configurations
      refine tier by tier in best-bound-first order, and any configuration
@@ -21,7 +21,8 @@ API.  Pricing a configuration space runs in five stages:
   5. **combine & rank** — fold cached values into estimates with the
      backend's (cheap, exact) combine arithmetic, record skipped and pruned
      configurations with reasons/bounds, and stable-sort by the backend's
-     key.
+     key.  Pallas cells (and GPU cells with ``machine_axis``) instead run
+     one batched rate stage per geometry group (DESIGN.md §11).
 
 The cache persists across calls, so a multi-machine or multi-kernel sweep
 (``explore``) pays for shared structure only once — and with
@@ -233,8 +234,7 @@ def _ckpt_key(run, top_k, machine_axis, strict) -> str | None:
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-_AXIS_METHODS = ("geometry_key", "machine_axis_tasks", "batch_order",
-                 "machine_axis_combine")
+_AXIS_METHODS = ("geometry_key", "machine_axis_tasks", "batch_order")
 
 
 class _AxisGroup:
@@ -251,8 +251,10 @@ class _AxisGroup:
 class _CellRun:
     """One (workload, backend, items, machine) cell mid-sweep."""
 
-    def __init__(self, wname, backend, items, machine, top_k, prune):
+    def __init__(self, wname, backend, items, machine, top_k, prune,
+                 sig=None):
         self.wname = wname
+        self.sig = sig                   # _cell_signature, None: unsignable
         self.backend = backend
         self.items = items
         self.machine = machine
@@ -434,13 +436,14 @@ class Explorer:
         ``top_k`` enables per-cell pruned search; ``progress(done, total)``
         is called as configurations reach a terminal state.
 
-        ``machine_axis=True`` switches to batched design-space evaluation
-        (DESIGN.md §11): cells sharing (workload structure, machine
-        geometry) price their structure once and run the rate/limiter stage
-        as one (configs x machines) array program, then build the selected
-        per-machine top-k entries through the scalar combine — results are
-        bitwise identical to the per-machine path.  Intended with ``top_k``
-        (full rankings fall back to per-entry scalar assembly).
+        Pallas cells are always priced batched (DESIGN.md §11): cells
+        sharing (workload structure, machine geometry) price their
+        structure once and run the rate stage as one (configs x machines)
+        array program, entries read from its rows.  ``machine_axis=True``
+        does the same for GPU cells, building the selected per-machine
+        top-k entries through the scalar combine — bitwise identical to
+        the per-machine path.  Intended with ``top_k`` (full GPU rankings
+        fall back to per-entry scalar assembly).
         """
         workloads = [
             w if isinstance(w, Workload) else Workload(name=w.name, gpu_spec=w)
@@ -501,10 +504,12 @@ class Explorer:
         just the cheap bound tasks (cache-shared with full sweeps — a warm
         cache makes this near-free) and orders configurations by their
         sound lower bound on primary time.  No grid walks, no wave model,
-        no worker pool.  Entries carry ``estimate=None``, ``perf=1/bound``
-        and ``limiter="bound"`` so they cannot be mistaken for exact
-        results; cells whose backend has no bound protocol are recorded as
-        skips rather than guessed at.
+        no worker pool; a Pallas cell's bound is its rate stage's
+        ``hbm_time + overhead``, its infeasible rows skipped.  Entries
+        carry ``estimate=None``, ``perf=1/bound`` and ``limiter="bound"``
+        so they cannot be mistaken for exact results; cells whose backend
+        has no bound protocol are recorded as skips rather than guessed
+        at.
         """
         workloads = [
             w if isinstance(w, Workload) else Workload(name=w.name, gpu_spec=w)
@@ -528,15 +533,18 @@ class Explorer:
         evals = 0
         with self.cache.hold():
             for wname, backend, items, machine in cells:
-                if not _prunable(backend):
+                batched = hasattr(backend, "batch_bounds")
+                if not (batched or _prunable(backend)):
                     report.skipped.append(SkippedConfig(
                         wname, machine.name, None,
                         "degraded pricing: backend has no closed-form "
                         "bound protocol"))
                     continue
-                rows = []
+                tasks_for = (backend.machine_axis_tasks if batched
+                             else backend.bound_tasks)
+                live, skips = [], []
                 for idx, item in enumerate(items):
-                    tasks = backend.bound_tasks(item, machine)
+                    tasks = tasks_for(item, machine)
                     for t in tasks:
                         if self.cache.lookup(t.key) is None:
                             self.cache.store(t.key,
@@ -545,12 +553,23 @@ class Explorer:
                     values: dict = {}
                     err = self._read_values(tasks, values, strict=False)
                     if err is not None:
-                        report.skipped.append(SkippedConfig(
-                            wname, machine.name, _item_config(item),
-                            f"{type(err).__name__}: {err}"))
-                        continue
-                    bound = backend.tier_bound(item, machine, values)
-                    rows.append((bound, idx, item))
+                        skips.append((idx, f"{type(err).__name__}: {err}"))
+                    else:
+                        live.append((idx, item, values))
+                if batched:
+                    bounds, bskips = backend.batch_bounds(
+                        [it for _, it, _ in live], [v for _, _, v in live],
+                        machine)
+                    rows = [(b, live[pos][0], live[pos][1])
+                            for pos, b in bounds]
+                    skips += [(live[pos][0], r) for pos, r in bskips]
+                else:
+                    rows = [(backend.tier_bound(item, machine, values), idx,
+                             item) for idx, item, values in live]
+                report.skipped.extend(
+                    SkippedConfig(wname, machine.name,
+                                  _item_config(items[idx]), reason)
+                    for idx, reason in sorted(skips, key=lambda s: s[0]))
                 # best (lowest) bound first; index breaks ties exactly like
                 # the exhaustive ranking's stable sort
                 rows.sort(key=lambda r: (r[0], r[1]))
@@ -642,7 +661,8 @@ class Explorer:
                 stats["shared_cells"] += 1
                 continue
             run = _CellRun(wname, backend, items, machine, top_k,
-                           prune=top_k is not None and _prunable(backend))
+                           prune=top_k is not None and _prunable(backend),
+                           sig=sig)
             runs.append(run)
             sources.append((wname, run))
             if sig is not None:
@@ -672,25 +692,31 @@ class Explorer:
                 else:
                     live_runs.append(run)
 
-        # machine-axis grouping (DESIGN.md §11): runs whose backend supports
-        # batched evaluation and whose (backend state, items, machine
-        # geometry) match become columns of one axis group; the rest flow
-        # through the per-machine paths unchanged
-        axis_groups, scalar_runs = [], live_runs
-        if machine_axis:
-            scalar_runs, by_axis = [], {}
-            for run in live_runs:
-                key = self._axis_key(run)
-                if key is None:
+        # batched grouping (DESIGN.md §11): Pallas runs always, GPU runs
+        # when ``machine_axis`` is asked; runs whose (backend state, items,
+        # machine geometry) match become columns of one group, priced by
+        # one rate-stage call.  The rest take the per-machine paths.
+        axis_groups, scalar_runs, by_axis = [], [], {}
+        for run in live_runs:
+            per_machine = hasattr(run.backend, "structural_tasks")
+            if per_machine and not machine_axis:
+                scalar_runs.append(run)
+                continue
+            key = self._axis_key(run)
+            if key is None:
+                if per_machine:
                     scalar_runs.append(run)
                     continue
-                grp = by_axis.get(key)
-                if grp is None:
-                    grp = _AxisGroup(run.backend, run.items)
-                    by_axis[key] = grp
-                    axis_groups.append(grp)
-                run.prune = False      # ranked by the batch, not the tiers
-                grp.runs.append(run)
+                # unsignable items: a group of its own
+                key = (id(run), run.backend.geometry_key(run.machine))
+            grp = by_axis.get(key)
+            if grp is None:
+                grp = _AxisGroup(run.backend, run.items)
+                by_axis[key] = grp
+                axis_groups.append(grp)
+            run.prune = False      # ranked by the batch, not the tiers
+            grp.runs.append(run)
+        if machine_axis:
             stats["geometry_groups"] = len(axis_groups)
             stats["machines_batched"] = sum(
                 len(g.runs) for g in axis_groups)
@@ -1002,12 +1028,10 @@ class Explorer:
         the run must take a per-machine path (backend without the batched
         protocol, or unsignable state)."""
         backend = run.backend
-        if not all(hasattr(backend, m) for m in _AXIS_METHODS):
+        if run.sig is None or not all(hasattr(backend, m)
+                                      for m in _AXIS_METHODS):
             return None
-        backend_sig = _backend_signature(backend)
-        items_sig = _items_signature(run.items)
-        if backend_sig is None or items_sig is None:
-            return None
+        backend_sig, items_sig, _ = run.sig
         try:
             gkey = backend.geometry_key(run.machine)
             key = (backend_sig, items_sig, type(run.machine).__name__, gkey)
@@ -1018,9 +1042,10 @@ class Explorer:
 
     def _run_machine_axis(self, groups, pool, strict, stats, advance):
         """Structure once per geometry group, one batched rate program per
-        group, scalar combine only for the selected per-machine entries —
-        so every returned estimate is bitwise identical to the per-machine
-        path by construction."""
+        group, and an entry built only for each machine's selected
+        configurations (Pallas: read from the rate rows; GPU: the scalar
+        combine, bitwise identical to the per-machine path).  Skips are
+        recorded in item order."""
         per_group_tasks = []
         all_tasks = []
         for g in groups:
@@ -1046,40 +1071,40 @@ class Explorer:
             if live_items:
                 with obs.span("engine.rate", items=len(live_items),
                               machines=len(machines)):
-                    orders, skip_lists = g.backend.batch_order(
+                    orders, skip_lists, entry = g.backend.batch_order(
                         live_items, live_values, machines)
             else:
                 orders = [[] for _ in machines]
                 skip_lists = [[] for _ in machines]
-            for run, order, skiplist in zip(g.runs, orders, skip_lists):
-                for idx, err in item_errs:
-                    self._skip(run, g.items[idx], err)
-                for pos, reason in skiplist:
-                    run.skips.append(SkippedConfig(
-                        run.wname, run.machine.name,
-                        _item_config(live_items[pos]), reason))
+            for m, (run, order, skiplist) in enumerate(
+                    zip(g.runs, orders, skip_lists)):
+                skips = [(idx, f"{type(err).__name__}: {err}")
+                         for idx, err in item_errs]
+                skips += [(live_idx[pos], reason) for pos, reason in skiplist]
                 sel = list(order)
                 if run.top_k is not None:
                     sel = sel[: run.top_k]
                 for pos in sel:
                     try:
-                        config, est, perf, limiter = (
-                            g.backend.machine_axis_combine(
-                                live_items[pos], run.machine,
-                                live_values[pos]))
+                        config, est, perf, limiter = entry(pos, m)
                     except (SkipConfig, ValueError, RuntimeError) as exc:
                         if strict and not isinstance(exc, SkipConfig):
                             raise
-                        run.skips.append(SkippedConfig(
-                            run.wname, run.machine.name,
-                            _item_config(live_items[pos]),
-                            f"{type(exc).__name__}: {exc}"))
+                        skips.append((live_idx[pos],
+                                      f"{type(exc).__name__}: {exc}"))
                         continue
                     run.add_result(EvalResult(
                         workload=run.wname, machine=run.machine.name,
                         backend=g.backend.name, index=live_idx[pos],
                         config=config, estimate=est, perf=perf,
                         limiter=limiter))
+                # results arrive ranked and cut to top_k
+                run._ranked = run.results
+                skips.sort(key=lambda s: s[0])
+                run.skips.extend(
+                    SkippedConfig(run.wname, run.machine.name,
+                                  _item_config(g.items[idx]), reason)
+                    for idx, reason in skips)
                 advance(len(run.items))
                 self._ckpt_store(run)
 

@@ -72,7 +72,10 @@ from repro import durable, faults, obs
 #       the geometry-keyed pallas-struct task (DESIGN.md §11)
 #   4 — Pallas feasibility counts Mosaic's VMEM reserve beside the
 #       footprint (pallas estimates and pallas-struct entries carry it)
-ENGINE_CACHE_VERSION = 4
+#   5 — Pallas cells keep only the geometry-keyed pallas-struct task (the
+#       per-machine pallas / pallas-bound tasks are gone) and record no
+#       pruned configurations
+ENGINE_CACHE_VERSION = 5
 
 _MAGIC = b"repro-invariant-cache"
 

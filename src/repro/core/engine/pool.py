@@ -110,6 +110,19 @@ def _pool_batch(calls: Sequence[tuple], ctx: tuple | None = None):
     return ("obs", out, scope.close("pool.chunk", "pool", tasks=len(calls)))
 
 
+def _submit(ex, calls, ctx) -> concurrent.futures.Future:
+    """Submit one chunk.  A worker that dies while chunks are still being
+    submitted breaks the executor, and ``submit`` then raises
+    ``BrokenProcessPool``; the chunk comes back as a future holding that
+    error, so it is retried like a chunk whose worker died."""
+    try:
+        return ex.submit(_pool_batch, calls, ctx)
+    except (OSError, RuntimeError) as exc:
+        f = concurrent.futures.Future()
+        f.set_exception(exc)
+        return f
+
+
 def default_workers() -> int:
     """Worker count: CPUs actually *available* to this process, optionally
     capped by ``REPRO_MAX_WORKERS``.
@@ -273,7 +286,11 @@ class TaskPool:
 
     def _kill_executor(self) -> None:
         """Tear down an executor presumed broken or hung.  ``shutdown``
-        alone would join hung workers forever, so terminate them first."""
+        alone would join hung workers forever, so terminate them first.
+        Then wait for its manager thread to end: a worker the next
+        executor forks while that thread still runs can inherit a lock it
+        holds (its wakeup pipe's), and then hangs at exit, where
+        ``concurrent.futures`` wakes every executor it knows of."""
         ex, self._executor = self._executor, None
         if ex is None:
             return
@@ -283,7 +300,7 @@ class TaskPool:
             except Exception:  # noqa: BLE001 — already-dead workers
                 pass
         try:
-            ex.shutdown(wait=False, cancel_futures=True)
+            ex.shutdown(wait=True, cancel_futures=True)
         except Exception:  # noqa: BLE001
             pass
 
@@ -324,8 +341,7 @@ class TaskPool:
                             [calls[i] for i in g])):
                         outcomes[i] = out
                 return outcomes
-            futures = [(g, ex.submit(_pool_batch,
-                                     [calls[i] for i in g], ctx))
+            futures = [(g, _submit(ex, [calls[i] for i in g], ctx))
                        for g in groups]
             failed, broken, progress = [], False, False
             for g, f in futures:

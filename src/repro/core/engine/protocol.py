@@ -90,7 +90,9 @@ class PrunedConfig:
 
 @runtime_checkable
 class Estimator(Protocol):
-    """What the Explorer requires of a backend (contract in DESIGN.md §5)."""
+    """What the Explorer requires of a per-machine backend (contract in
+    DESIGN.md §5).  A backend without ``structural_tasks`` (Pallas) is
+    priced only through the batched methods below."""
 
     name: str
 
@@ -138,6 +140,12 @@ class Estimator(Protocol):
     #     """The ascending scalar ``tier_bound`` bounds (e.g. predicted
     #     time per work unit); must order identically to the leading
     #     component of ``sort_key``."""
+
+    # ---- batched rate stage over machines (DESIGN.md §11) --------------
+    # Used with ``machine_axis``, and always for a backend without the
+    # methods above: ``geometry_key(machine)``, ``machine_axis_tasks(item,
+    # machine)`` and ``batch_order(items, values_per_item, machines) ->
+    # (orders, skips, entry)``; ``batch_bounds`` serves degraded pricing.
 
 
 @dataclass

@@ -22,12 +22,17 @@ analytically, before any lowering:
   * **Multi-limiter time**: with Mosaic's double-buffered pipeline, compute
     overlaps DMA, so T = max(T_mxu+T_vpu, T_hbm, T_vmem) + grid overhead.
 
+The model runs in two stages: ``pallas_structure`` (counts, keyed by tile
+geometry) and ``pallas_rate_matrix`` (the formula above, over candidates x
+machines, written nowhere else).  ``estimate_pallas`` is one row of it.
+
 ``select_pallas_config`` ranks candidate block configurations — replacing
 autotuning exactly as the paper does for thread-block sizes.
 """
 from __future__ import annotations
 
 import math
+from collections import namedtuple
 from dataclasses import dataclass, field as dc_field
 from typing import Callable, Iterable, Sequence
 
@@ -204,13 +209,8 @@ class PallasEstimate:
 
 
 def hbm_traffic(spec: PallasKernelSpec) -> tuple:
-    """HBM traffic via revisit analysis: ``(hbm_bytes, per_operand detail)``.
-
-    Closed-form BlockSpec byte counting — cheap enough that the tiered
-    search (engine §5) uses it, plus the grid overhead, as the sound lower
-    bound on predicted time before running the full estimate.  Factored out
-    of ``estimate_pallas`` so bound and estimate share the exact float ops.
-    """
+    """HBM traffic via revisit analysis: ``(hbm_bytes, per_operand detail)``,
+    closed-form BlockSpec byte counting."""
     hbm_bytes = 0.0
     per_op = {}
     for op in spec.operands:
@@ -271,20 +271,6 @@ def vmem_violation(vmem_alloc, vmem_reserve_bytes, machine) -> str:
             f"{machine.vmem_bytes} B VMEM")
 
 
-def pallas_time_floor(spec: PallasKernelSpec,
-                      machine: TPUMachine = TPU_V5E) -> float:
-    """Lower bound on ``estimate_pallas(...).total_time`` from HBM volume
-    and grid overhead alone (no issue model, no VMEM residency).
-
-    Sound by construction: the estimate's total is ``max(compute, hbm_time,
-    vmem_time) + overhead`` with both terms computed by the identical float
-    operations used here, and ``max``/``+`` are monotone in IEEE arithmetic.
-    """
-    n_steps = math.prod(spec.grid) if spec.grid else 1
-    hbm_bytes, _ = hbm_traffic(spec)
-    return hbm_bytes / machine.hbm_bw + n_steps * machine.grid_step_overhead_s
-
-
 def pallas_structure(spec: PallasKernelSpec, geometry) -> dict:
     """Geometry-keyed structural stage of the Pallas model (DESIGN.md §11).
 
@@ -292,8 +278,7 @@ def pallas_structure(spec: PallasKernelSpec, geometry) -> dict:
     ``sublane_elems``, ``mxu_dim``) — everything here depends on tile
     paddings and the grid, never on bandwidths, FLOP peaks, or the VMEM
     *capacity* budget, so all rate variants of one geometry share this
-    computation.  Mirrors ``estimate_pallas``'s float operations exactly
-    (the property tests pin the batched path bitwise-equal to it).
+    computation.  ``pallas_rate_matrix`` turns it into times.
     """
     n_steps = math.prod(spec.grid) if spec.grid else 1
     hbm_bytes, per_op = hbm_traffic(spec)
@@ -326,111 +311,76 @@ def pallas_structure(spec: PallasKernelSpec, geometry) -> dict:
 
 PALLAS_LIMITERS = ("MXU", "VPU", "HBM", "VMEM")
 
+# one candidate on one machine, as the rate stage prices it
+PallasRow = namedtuple("PallasRow", (
+    "hbm_time", "mxu_time", "vpu_time", "vmem_time", "overhead", "total",
+    "limiter", "feasible"))
 
-def pallas_rate_matrix(structs, machines):
-    """Rate stage over ``(candidates x machines)`` (DESIGN.md §11).
 
-    Returns ``(total, limiter_idx, feasible)``: predicted total time,
-    limiter indices into ``PALLAS_LIMITERS``, and the VMEM-residency
-    feasibility mask.  Bitwise contract with ``estimate_pallas``: identical
-    operation order per element; the limiter replicates the scalar path's
-    dict-collapse tie semantics (equal float keys keep the *last* inserted
-    label over the insertion order compute, hbm, vmem — emulated with an
-    argmax over the reversed stack).
+def pallas_rate_matrix(structs, machines) -> list:
+    """The rate stage of the TPU model over ``(candidates x machines)``,
+    the one place its formula is written (DESIGN.md §2, §11):
+
+        T = max(T_mxu + T_vpu, T_hbm, T_vmem) + n_steps * overhead
+
+    Returns per machine one ``PallasRow`` per structure: the terms, their
+    ``total``, the ``limiter`` index into ``PALLAS_LIMITERS`` and VMEM
+    residency (``feasible``).  Among equal maxima the limiter is the last
+    of compute, HBM, VMEM; compute is MXU unless VPU time exceeds MXU
+    time.  ``hbm_time + overhead`` is the HBM-volume floor under ``total``
+    (``max`` and ``+`` are monotone in IEEE arithmetic).  Plain Python: a
+    generator's space on a handful of machines is a few hundred pairs at
+    most, where numpy's per-call cost exceeds the arithmetic.
     """
-    import numpy as np
-
-    f = lambda xs: np.array(list(xs), dtype=float)  # noqa: E731
-    n_steps = f(s["n_steps"] for s in structs)
-    hbm_bytes = f(s["hbm_bytes"] for s in structs)
-    mxu_flops = f(s["mxu_flops"] for s in structs)
-    vpu_elems = f(s["vpu_elems"] for s in structs)
-    vmem_touch = f(s["vmem_touch"] for s in structs)
-    vmem_need = f(s["vmem_alloc"] + s["vmem_reserve"] for s in structs)
-    bf16 = np.array([s["elem_bytes"] <= 2 for s in structs], dtype=bool)
-
-    hbm_bw = f(m.hbm_bw for m in machines)
-    vmem_bw = f(m.vmem_bw for m in machines)
-    vpu_flops = f(m.vpu_flops for m in machines)
-    vmem_bytes = f(m.vmem_bytes for m in machines)
-    overhead_s = f(m.grid_step_overhead_s for m in machines)
-    peak = np.where(bf16[:, None],
-                    f(m.peak_flops_bf16 for m in machines)[None, :],
-                    f(m.peak_flops_f32 for m in machines)[None, :])
-
-    C, M = len(structs), len(machines)
-    hbm_time = hbm_bytes[:, None] / hbm_bw[None, :]
-    mxu_time = (n_steps * mxu_flops)[:, None] / peak
-    vpu_time = (n_steps * vpu_elems)[:, None] / vpu_flops[None, :]
-    vmem_time = vmem_touch[:, None] / vmem_bw[None, :]
-    compute = mxu_time + vpu_time
-    three = np.stack([compute,
-                      np.broadcast_to(hbm_time, (C, M)),
-                      np.broadcast_to(vmem_time, (C, M))])
-    total = three.max(axis=0) + n_steps[:, None] * overhead_s[None, :]
-    # scalar limiter: {compute: MXU/VPU, hbm: HBM, vmem: VMEM}[max] — among
-    # equal maxima the last-inserted key's label survives the dict collapse
-    last_max = 2 - np.argmax(three[::-1], axis=0)
-    limiter_idx = np.where(
-        last_max == 0, np.where(mxu_time >= vpu_time, 0, 1),
-        np.where(last_max == 1, 2, 3))
-    feasible = vmem_need[:, None] <= vmem_bytes[None, :]
-    return total, limiter_idx, feasible
+    rows = []
+    for m in machines:
+        mrows = []
+        for s in structs:
+            n = s["n_steps"]
+            hbm = s["hbm_bytes"] / m.hbm_bw
+            mxu = n * s["mxu_flops"] / m.peak_flops(s["elem_bytes"])
+            vpu = n * s["vpu_elems"] / m.vpu_flops
+            vmem = s["vmem_touch"] / m.vmem_bw
+            compute = mxu + vpu
+            overhead = n * m.grid_step_overhead_s
+            limiter = (3 if vmem >= max(compute, hbm) else
+                       2 if hbm >= compute else 0 if mxu >= vpu else 1)
+            mrows.append(PallasRow(
+                hbm, mxu, vpu, vmem, overhead,
+                max(compute, hbm, vmem) + overhead, limiter,
+                s["vmem_alloc"] + s["vmem_reserve"] <= m.vmem_bytes))
+        rows.append(mrows)
+    return rows
 
 
-def estimate_pallas(spec: PallasKernelSpec, machine: TPUMachine = TPU_V5E) -> PallasEstimate:
-    n_steps = math.prod(spec.grid) if spec.grid else 1
-
-    # ---- HBM traffic via revisit analysis ------------------------------
-    hbm_bytes, per_op = hbm_traffic(spec)
-    hbm_time = hbm_bytes / machine.hbm_bw
-
-    # ---- VMEM residency (layer condition as feasibility) ---------------
-    vmem_alloc = vmem_footprint(spec.operands, spec.scratch_bytes, machine)
-    vmem_reserve_bytes = spec_vmem_reserve(spec, machine)
-    feasible = vmem_alloc + vmem_reserve_bytes <= machine.vmem_bytes
-
-    # ---- compute issue model -------------------------------------------
-    mxu_flops = sum(m.padded_flops(machine, spec.elem_bytes) for m in spec.matmuls_per_step)
-    mxu_time = n_steps * mxu_flops / machine.peak_flops(spec.elem_bytes)
-    vpu_elems = spec.vpu_elems_per_step
-    if spec.vpu_shape and len(spec.vpu_shape) >= 2:
-        sub = machine.sublane_elems(spec.elem_bytes)
-        pad = (
-            _roundup(spec.vpu_shape[-2], sub)
-            * _roundup(spec.vpu_shape[-1], machine.vpu_lanes)
-        ) / max(spec.vpu_shape[-2] * spec.vpu_shape[-1], 1)
-        vpu_elems *= pad
-    vpu_time = n_steps * vpu_elems / machine.vpu_flops
-
-    # ---- VMEM<->VREG traffic -------------------------------------------
-    vmem_touch = sum(op.block_bytes() for op in spec.operands) * n_steps
-    vmem_time = vmem_touch / machine.vmem_bw
-
-    compute = mxu_time + vpu_time
-    overhead = n_steps * machine.grid_step_overhead_s
-    total = max(compute, hbm_time, vmem_time) + overhead
-    limiter = {
-        compute: "MXU" if mxu_time >= vpu_time else "VPU",
-        hbm_time: "HBM",
-        vmem_time: "VMEM",
-    }[max(compute, hbm_time, vmem_time)]
+def pallas_estimate(name: str, struct: dict, row: PallasRow) -> PallasEstimate:
+    """The estimate of one candidate from its structure and its row."""
     return PallasEstimate(
-        kernel=spec.name,
-        hbm_bytes=hbm_bytes,
-        hbm_time=hbm_time,
-        mxu_time=mxu_time,
-        vpu_time=vpu_time,
-        vmem_time=vmem_time,
-        vmem_alloc_bytes=vmem_alloc,
-        grid_overhead=overhead,
-        total_time=total,
-        limiter=limiter,
-        feasible=feasible,
-        work=spec.work_per_step * n_steps,
-        detail={"per_operand": per_op, "n_steps": n_steps,
-                "vmem_reserve": vmem_reserve_bytes},
+        kernel=name,
+        hbm_bytes=struct["hbm_bytes"],
+        hbm_time=row.hbm_time,
+        mxu_time=row.mxu_time,
+        vpu_time=row.vpu_time,
+        vmem_time=row.vmem_time,
+        vmem_alloc_bytes=struct["vmem_alloc"],
+        grid_overhead=row.overhead,
+        total_time=row.total,
+        limiter=PALLAS_LIMITERS[row.limiter],
+        feasible=row.feasible,
+        work=struct["work"],
+        detail={"per_operand": struct["per_op"],
+                "n_steps": struct["n_steps"],
+                "vmem_reserve": struct["vmem_reserve"]},
     )
+
+
+def estimate_pallas(spec: PallasKernelSpec,
+                    machine: TPUMachine = TPU_V5E) -> PallasEstimate:
+    """One spec on one machine: its structure, then one row of the rate
+    stage."""
+    struct = pallas_structure(spec, machine.geometry)
+    [[row]] = pallas_rate_matrix([struct], [machine])
+    return pallas_estimate(spec.name, struct, row)
 
 
 @dataclass
@@ -449,16 +399,14 @@ def select_pallas_config(
     """Rank (config_dict, PallasKernelSpec) candidates by predicted time.
 
     Routes through the exploration engine (``repro.core.engine``), which
-    memoizes per-spec estimates across sweeps: infeasible candidates (VMEM
-    oversubscription — the violated layer condition) are recorded in the
-    engine report's ``skipped`` list with their reason; ties break toward
-    smaller VMEM footprints.  Pass an ``Explorer`` as ``engine`` to share
-    its cache across calls.  ``top_k`` runs the engine's bound-then-refine
-    search (HBM-volume time floors prune before full estimates) — the
-    returned head is bitwise identical to exhaustive ranking, but a
-    candidate pruned by its bound lands in ``report.pruned`` without its
-    estimate ever running, so VMEM infeasibility beyond the top-k may go
-    undiagnosed; use an exhaustive ranking to audit the layer condition.
+    memoizes per-spec structures across sweeps and prices every candidate
+    in one rate-stage call: infeasible candidates (VMEM oversubscription —
+    the violated layer condition) are recorded in the engine report's
+    ``skipped`` list with their reason; ties break toward smaller VMEM
+    footprints.  Pass an ``Explorer`` as ``engine`` to share its cache
+    across calls.  ``top_k`` keeps the exhaustive ranking's first k; no
+    candidate is pruned (``report.pruned`` stays empty), and infeasible
+    candidates beyond the first k are still recorded in ``skipped``.
     """
     from .engine import Explorer
 

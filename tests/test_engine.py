@@ -179,6 +179,29 @@ def test_pallas_infeasible_candidates_skipped_with_reason():
     assert times == sorted(times)
 
 
+def test_pallas_degraded_bounds_are_the_rate_stage_hbm_floor():
+    """``bound_rank`` prices a Pallas cell by its rows' ``hbm_time +
+    overhead``, a lower bound on each total, and skips what the exact
+    ranking skips, with the same reasons."""
+    from repro.kernels.stencil3d25.generator import candidate_specs
+
+    cands = list(candidate_specs(4, (512, 2048, 2048), elem_bytes=8))
+    exact = Explorer()._rank_pallas(cands, TPU_V5E, workload="st")
+    degraded = Explorer().bound_rank(
+        [Workload(name="st", tpu_candidates=cands)], [TPU_V5E])
+    assert exact.skipped, "huge planes must violate the VMEM layer condition"
+    assert [(s.config, s.reason) for s in degraded.skipped] == \
+        [(s.config, s.reason) for s in exact.skipped]
+    by_config = {repr(e.config): e.estimate for e in exact.entries}
+    assert sorted(by_config) == sorted(repr(e.config)
+                                       for e in degraded.entries)
+    for e in degraded.entries:
+        est = by_config[repr(e.config)]
+        assert (e.limiter, e.estimate) == ("bound", None)
+        floor = est.hbm_time + est.grid_overhead
+        assert e.perf == 1.0 / floor and floor <= est.total_time
+
+
 def test_vectorized_walks_match_loop_oracle():
     cases = [
         (star_stencil_3d(r=1, domain=(13, 17, 33)), (32, 4, 8), (1, 1, 1)),

@@ -149,6 +149,35 @@ def test_poison_tasks_quarantined_parent_survives():
     assert verdict["health"]["rebuilds"] >= 2
 
 
+# prepended to a scenario: the parent sleeps after each chunk it submits,
+# so a worker killed by its first chunk breaks the executor while chunks
+# are still being submitted (the race a loaded host produces)
+_SLOW_SUBMIT = """
+import time
+from concurrent.futures import ProcessPoolExecutor
+_submit = ProcessPoolExecutor.submit
+def _slow_submit(self, *args, **kwargs):
+    future = _submit(self, *args, **kwargs)
+    time.sleep(0.3)
+    return future
+ProcessPoolExecutor.submit = _slow_submit
+"""
+
+
+def test_worker_death_during_submission_crash_recovers(tmp_path):
+    verdict = _run_scenario(_SLOW_SUBMIT + _CRASH_RECOVERY, str(tmp_path))
+    assert verdict["identical"], "recovered outcomes differ from fault-free"
+    assert verdict["health"]["rebuilds"] >= 1
+    assert verdict["health"]["quarantined"] == 0
+    assert verdict["tokens"] == ["pool_worker_crash.0.token"]
+
+
+def test_worker_death_during_submission_poison_quarantined():
+    verdict = _run_scenario(_SLOW_SUBMIT + _POISON_QUARANTINE)
+    assert verdict["all_poisoned"]
+    assert verdict["health"]["quarantined"] == 4
+
+
 _ENGINE_RECOVERY = """
 import json, sys
 from repro import faults
